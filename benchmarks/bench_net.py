@@ -225,7 +225,9 @@ def _process_cluster(sessions: int = 24, loss: float = 0.10,
     ports = _free_ports(3)
     members = [f"gw{i}@127.0.0.1:{ports[i]}" + (f"@z{i}" if zones else "")
                for i in range(3)]
-    env = {**os.environ,
+    # the children gossip session metadata only; on CPU they never
+    # contend with this process for its accelerator
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": REPO_SRC + (os.pathsep + os.environ["PYTHONPATH"]
                                      if os.environ.get("PYTHONPATH")
                                      else "")}

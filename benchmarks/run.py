@@ -118,14 +118,8 @@ def main(argv=None) -> None:
                              f"have {[n for n, _ in modules]}")
         modules = [(n, m) for n, m in modules if n in keep]
 
-    try:        # kernel-launch accounting rides along when jax is present
-        from repro.kernels.ops import counters as _kernel_counters
-    except Exception:  # pragma: no cover - partial installs
-        _kernel_counters = None
-    try:        # per-suite metrics snapshots from the obs registry
-        from repro.obs import reset_global_registry as _reset_registry
-    except Exception:  # pragma: no cover - partial installs
-        _reset_registry = None
+    from repro.kernels.ops import counters as _kernel_counters
+    from repro.obs import reset_global_registry as _reset_registry
 
     print("name,us_per_call,derived")
     results = {}
@@ -136,11 +130,10 @@ def main(argv=None) -> None:
     run_t0 = time.perf_counter()
     for name, mod in modules:
         t0 = time.perf_counter()
-        snap = (_kernel_counters.snapshot() if _kernel_counters is not None
-                else None)
+        snap = _kernel_counters.snapshot()
         # each suite gets a fresh process-wide registry, so its snapshot
         # (marker lags, queue drops, redundancy gauges) is per-suite
-        reg = _reset_registry() if _reset_registry is not None else None
+        reg = _reset_registry()
         try:
             rows = mod.run()
         except Exception as e:  # report, keep going
@@ -157,12 +150,10 @@ def main(argv=None) -> None:
             }
         dt = time.perf_counter() - t0
         suite_wall[name] = round(dt, 3)
-        if snap is not None:
-            suite_launches[name] = _kernel_counters.since(snap)["launches"]
-        if reg is not None:
-            metrics = json.loads(reg.render_json())   # NaN/Inf cleaned
-            if metrics:
-                suite_metrics[name] = metrics
+        suite_launches[name] = _kernel_counters.since(snap)["launches"]
+        metrics = json.loads(reg.render_json())   # NaN/Inf cleaned
+        if metrics:
+            suite_metrics[name] = metrics
         print(f"# {name} done in {dt:.1f}s", file=sys.stderr)
     if args.json:
         with open(args.json, "w") as f:

@@ -111,7 +111,7 @@ def main() -> None:
     from repro.launch.train import run_delta
 
     class A:  # tiny args namespace
-        arch = "qwen1.5-0.5b"
+        arch, reduced = "qwen1.5-0.5b", True
         seq, batch, lr, seed = 64, 4, 1e-3, 0
         steps, local_steps, pods = 9, 3, 3
         net_loss, topk = 0.2, None

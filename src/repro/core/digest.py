@@ -164,7 +164,7 @@ def store_digest(store: LatticeStore) -> StoreDigest:
         if sc is not None and sc is not False:   # False = "not stackable"
             spans, vers_col = sc.spans, sc.vers
     for key, val in store.entries:
-        if ts_cls is not None and isinstance(val, ts_cls):
+        if isinstance(val, ts_cls):
             from .tensor_lattice import dense_versions
             for name, ct in val.chunks:
                 span = spans.get((key, name)) if spans is not None else None
@@ -292,7 +292,7 @@ def digest_diff(store: LatticeStore, digest: StoreDigest) -> LatticeStore:
         if q_epoch > epoch:
             continue                 # requester's incarnation dominates
         same_epoch = q_epoch == epoch
-        if ts_cls is None or not isinstance(val, ts_cls):
+        if not isinstance(val, ts_cls):
             if isinstance(val, _causal_wire_types()):
                 g = digest.causal.get(key) if same_epoch else None
                 if g is None:

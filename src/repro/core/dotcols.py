@@ -175,14 +175,6 @@ def _jax_missing_kernel(has_cloud: bool):
     return jax.jit(kernel)
 
 
-def _jax_default() -> bool:
-    try:
-        from ..kernels import ops
-        return ops.use_pallas_default()
-    except Exception:  # pragma: no cover - partial installs
-        return False
-
-
 def missing_mask(vvcol: np.ndarray, cloudcol: np.ndarray,
                  dots: np.ndarray, backend: Optional[str] = None
                  ) -> np.ndarray:
@@ -198,15 +190,17 @@ def missing_mask(vvcol: np.ndarray, cloudcol: np.ndarray,
     """
     if dots.size == 0:
         return np.zeros(0, bool)
+    from ..kernels import ops
     if backend is None:
-        backend = ("jax" if dots.size >= _JIT_MIN_ROWS and _jax_default()
-                   else "numpy")
+        backend = ("jax" if dots.size >= _JIT_MIN_ROWS
+                   and ops.use_pallas_default() else "numpy")
     if backend == "jax":
         # packed dots need all 64 bits (rid<<48 | seq); jax truncates to
         # int32 unless x64 is scoped on around both trace and call
-        from jax.experimental import enable_x64
+        import jax
+        ops.record_launch("missing_mask", vvcol, cloudcol, dots)
         kern = _jax_missing_kernel(bool(cloudcol.size))
-        with enable_x64():
+        with jax.enable_x64(True):
             return np.asarray(kern(vvcol, cloudcol, dots))
     rid = dots >> SEQ_BITS
     seq = dots & SEQ_MASK

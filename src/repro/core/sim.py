@@ -26,6 +26,8 @@ import random
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+import numpy as np
+
 
 # ---------------------------------------------------------------------------
 # Structural size accounting (the Õ(·) of §9: counts of atoms, ignoring
@@ -44,12 +46,8 @@ def structural_size(x: Any) -> int:
         return len(x)
     if isinstance(x, (int, float, str, bool)):
         return 1
-    try:
-        import numpy as _np
-        if isinstance(x, _np.ndarray):
-            return int(x.size)    # digest version columns in object mode
-    except ImportError:  # pragma: no cover
-        pass
+    if isinstance(x, np.ndarray):
+        return int(x.size)        # digest version columns in object mode
     if isinstance(x, (list, tuple, set, frozenset)):
         return sum(structural_size(v) for v in x)
     if isinstance(x, dict):

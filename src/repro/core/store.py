@@ -308,23 +308,16 @@ def _joined_life(a, b) -> Tuple[Tuple[str, Life], ...]:
 # Batched TensorState join (one Pallas launch over many keys' chunks)
 # ---------------------------------------------------------------------------
 
-_TS_CLS = None     # cached TensorState class (lazy: tensor_lattice pulls jax)
-
-
 def _tensorstate_cls():
-    global _TS_CLS
-    if _TS_CLS is None:
-        try:
-            from .tensor_lattice import TensorState
-        except Exception:  # pragma: no cover - jax unavailable
-            return None
-        _TS_CLS = TensorState
-    return _TS_CLS
+    """``TensorState``, imported on first use: pure-CRDT stores never
+    pull in ``tensor_lattice`` (and with it jax)."""
+    from .tensor_lattice import TensorState
+    return TensorState
 
 
 def _both_tensorstates(a: Any, b: Any) -> bool:
     ts = _tensorstate_cls()
-    return ts is not None and isinstance(a, ts) and isinstance(b, ts)
+    return isinstance(a, ts) and isinstance(b, ts)
 
 
 def _stackable(act, bct) -> bool:
@@ -374,8 +367,8 @@ def _stack_store(store: LatticeStore):
     ts_cls = _tensorstate_cls()
     result = None
     # cheap prescan first so non-tensor stores bail before any array work
-    if (ts_cls is not None and store.entries
-            and all(isinstance(v, ts_cls) for _, v in store.entries)):
+    if store.entries and all(isinstance(v, ts_cls)
+                             for _, v in store.entries):
         parts_v, parts_r, layout = [], [], []
         chunkw = dtype = vdtype = None
         row = 0
@@ -484,8 +477,6 @@ def _patched_fast_join(a_store: LatticeStore,
     if not isinstance(sa, _StackedChunks) or not b_store.entries:
         return None
     ts_cls = _tensorstate_cls()
-    if ts_cls is None:
-        return None
     from .tensor_lattice import live_rows
 
     chunkw = sa.sig[2]

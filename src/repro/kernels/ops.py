@@ -1,7 +1,7 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret=`` selects Pallas interpret mode (CPU validation; this
-container has no TPU). On TPU hardware call with ``interpret=False``.
+``interpret=`` selects Pallas interpret mode (CPU validation). On TPU
+hardware call with ``interpret=False``.
 ``use_pallas_default()`` is consulted by the model stack: XLA fallbacks
 (the same math, from the oracles) are used for the 512-device dry-run,
 because a TPU Mosaic kernel does not compile on the CPU backend. The new
@@ -25,13 +25,14 @@ device. Benchmarks snapshot/diff the counters around each round.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import ref
+from .delta_join import ROW_TILE, padded_rows
 from .delta_join import batched_delta_join as _batched_delta_join
 from .delta_join import chunk_digest as _chunk_digest
 from .delta_join import delta_join as _delta_join
@@ -43,8 +44,8 @@ from .flash_attention import flash_decode_fwd as _flash_decode
 
 def use_pallas_default() -> bool:
     """Whether the Mosaic Pallas kernels compile on the current backend.
-    On TPU call the kernels with ``interpret=False``; elsewhere (this
-    container: CPU) use interpret mode / the XLA oracles."""
+    On TPU call the kernels with ``interpret=False``; elsewhere use
+    interpret mode / the XLA oracles."""
     return jax.default_backend() == "tpu"
 
 
@@ -61,7 +62,9 @@ class KernelCounters:
     every *numpy* operand handed to a launch (device-resident jax.Array
     operands cost nothing — that is the resident store's whole claim).
     ``d2h_bytes`` counts bytes explicitly pulled back to host
-    (:meth:`count_d2h` — spills, ranking results).
+    (:meth:`count_d2h` — spills, ranking results). ``by_kernel`` splits
+    the launches by ``"name:mode"`` (see :func:`record_launch`), so a run
+    can show that its kernels ran compiled on the device.
 
     The counters are monotone for the process lifetime and are read by
     **snapshot-and-diff only** (:meth:`snapshot` / :meth:`since`): a
@@ -75,12 +78,13 @@ class KernelCounters:
     totals this way.
     """
 
-    __slots__ = ("launches", "h2d_bytes", "d2h_bytes")
+    __slots__ = ("launches", "h2d_bytes", "d2h_bytes", "by_kernel")
 
     def __init__(self):
         self.launches = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        self.by_kernel: Dict[str, int] = {}   # "name:mode" → launches
 
     def snapshot(self) -> dict:
         return {"launches": self.launches, "h2d_bytes": self.h2d_bytes,
@@ -116,16 +120,24 @@ def set_launch_hook(fn: Optional[Callable[[str, int], None]]) -> None:
     _launch_hook = fn
 
 
-def record_launch(name: str, *operands) -> None:
+def record_launch(name: str, *operands, mode: str = "xla") -> None:
     """Account one named kernel dispatch: bump the counters and notify
     the launch hook. Every wrapper (and any out-of-module launch site,
     e.g. the resident store's ranking epilogue) routes through here so
-    launches are observable by name, not just as a bare count."""
+    launches are observable by name, not just as a bare count. ``mode``
+    says what ran: ``"compiled"`` (a Mosaic kernel), ``"interpret"``
+    (Pallas interpret mode) or ``"xla"`` (a jitted XLA program)."""
     counters.launches += 1
+    key = f"{name}:{mode}"
+    counters.by_kernel[key] = counters.by_kernel.get(key, 0) + 1
     before = counters.h2d_bytes
     counters.count_h2d(*operands)
     if _launch_hook is not None:
         _launch_hook(name, counters.h2d_bytes - before)
+
+
+def _mode(interpret: bool) -> str:
+    return "interpret" if interpret else "compiled"
 
 
 # ---------------------------------------------------------------------------
@@ -146,7 +158,7 @@ def flash_attention(q, k, v, *, scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False):
     """Causal flash attention. q [b,h,s,hd]; k,v [b,kv,s,hd]."""
-    record_launch("flash_attention", q, k, v)
+    record_launch("flash_attention", q, k, v, mode=_mode(interpret))
     return _flash_attention_jit(q, k, v, scale=scale, window=window,
                                 softcap=softcap, block_q=block_q,
                                 block_k=block_k, interpret=interpret)
@@ -157,7 +169,8 @@ def flash_decode(q, k, v, q_pos, k_pos, *, scale: Optional[float] = None,
                  softcap: Optional[float] = None,
                  block_k: int = 128, interpret: bool = False):
     """One-token decode against a (ring) KV cache with slot positions."""
-    record_launch("flash_decode", q, k, v, q_pos, k_pos)
+    record_launch("flash_decode", q, k, v, q_pos, k_pos,
+                  mode=_mode(interpret))
     return _flash_decode_jit(q, k, v, q_pos, k_pos, scale=scale,
                              window=window, softcap=softcap,
                              block_k=block_k, interpret=interpret)
@@ -171,15 +184,16 @@ _delta_join_jit = functools.partial(
     jax.jit, static_argnames=("block_n", "interpret"))(_delta_join)
 
 
-def delta_join(a_vals, a_vers, b_vals, b_vers, *, block_n: int = 256,
+def delta_join(a_vals, a_vers, b_vals, b_vers, *, block_n: int = ROW_TILE,
                interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Fused versioned-chunk LWW merge (the δ-CRDT tensor join hot loop)."""
-    record_launch("delta_join", a_vals, a_vers, b_vals, b_vers)
+    record_launch("delta_join", a_vals, a_vers, b_vals, b_vers,
+                  mode=_mode(interpret))
     return _delta_join_jit(a_vals, a_vers, b_vals, b_vers, block_n=block_n,
                            interpret=interpret)
 
 
-def batched_delta_join(segments, *, block_n: int = 256,
+def batched_delta_join(segments, *, block_n: int = ROW_TILE,
                        interpret: bool = False, host_stage: bool = False):
     """Stacked versioned-chunk merge over many objects' chunks: segments
     sharing a (chunk-width, dtype) signature run as ONE kernel launch
@@ -201,23 +215,23 @@ _chunk_digest_jit = functools.partial(
 _chunk_digest_ref_jit = jax.jit(ref.chunk_digest_ref)
 
 
-def chunk_digest(x, *, block_n: int = 256,
+def chunk_digest(x, *, block_n: int = ROW_TILE,
                  interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Per-chunk (max|x|, Σx²) in one pass — delta-selection digests."""
-    record_launch("chunk_digest", x)
+    record_launch("chunk_digest", x, mode=_mode(interpret))
     return _chunk_digest_jit(x, block_n=block_n, interpret=interpret)
 
 
-def chunk_digest_auto(x, *, block_n: int = 256
+def chunk_digest_auto(x, *, block_n: int = ROW_TILE
                       ) -> Tuple[jax.Array, jax.Array]:
     """:func:`chunk_digest` on the best backend available: compiled
     Pallas on TPU, the jitted XLA oracle elsewhere (identical math, one
     fused dispatch either way). The digest-selection hot path calls this
     instead of paying interpret mode's per-grid-step simulation cost per
     tensor."""
-    record_launch("chunk_digest", x)
     if use_pallas_default():
-        return _chunk_digest_jit(x, block_n=block_n, interpret=False)
+        return chunk_digest(x, block_n=block_n)
+    record_launch("chunk_digest", x, mode="xla")
     return _chunk_digest_ref_jit(x)
 
 
@@ -227,21 +241,21 @@ _fused_join_digest_ref_jit = jax.jit(ref.fused_join_digest_ref)
 
 
 def fused_join_digest(a_vals, a_vers, b_vals, b_vers, *,
-                      block_n: int = 256,
+                      block_n: int = ROW_TILE,
                       interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Join + digest-of-the-merge in ONE launch: ``(out_vals, out_vers,
     max|out| per chunk, Σout² per chunk)``. ``interpret=None`` (default)
     auto-dispatches — compiled Pallas on TPU, the jitted XLA oracle
     elsewhere; pass True/False to force a Pallas mode (parity tests)."""
-    record_launch("fused_join_digest", a_vals, a_vers, b_vals, b_vers)
-    if interpret is None:
-        if use_pallas_default():
-            return _fused_join_digest_jit(a_vals, a_vers, b_vals, b_vers,
-                                          block_n=block_n, interpret=False)
-        return _fused_join_digest_ref_jit(a_vals, a_vers, b_vals, b_vers)
-    return _fused_join_digest_jit(a_vals, a_vers, b_vals, b_vers,
-                                  block_n=block_n, interpret=interpret)
+    operands = (a_vals, a_vers, b_vals, b_vers)
+    if interpret is None and not use_pallas_default():
+        record_launch("fused_join_digest", *operands, mode="xla")
+        return _fused_join_digest_ref_jit(*operands)
+    interpret = bool(interpret)
+    record_launch("fused_join_digest", *operands, mode=_mode(interpret))
+    return _fused_join_digest_jit(*operands, block_n=block_n,
+                                  interpret=interpret)
 
 
 _scatter_join_jit = functools.partial(
@@ -258,15 +272,13 @@ def scatter_join(vals, vers, maxabs, sumsq, idx, d_vals, d_vers, *,
     :func:`fused_join_digest`. ``idx`` empty is a no-op (no launch)."""
     if int(idx.shape[0]) == 0:
         return vals, vers, maxabs, sumsq
-    record_launch("scatter_join", vals, vers, maxabs, sumsq, idx, d_vals, d_vers)
-    if interpret is None:
-        if use_pallas_default():
-            return _scatter_join_jit(vals, vers, maxabs, sumsq, idx,
-                                     d_vals, d_vers, interpret=False)
-        return _scatter_join_ref_jit(vals, vers, maxabs, sumsq, idx,
-                                     d_vals, d_vers)
-    return _scatter_join_jit(vals, vers, maxabs, sumsq, idx, d_vals,
-                             d_vers, interpret=interpret)
+    operands = (vals, vers, maxabs, sumsq, idx, d_vals, d_vers)
+    if interpret is None and not use_pallas_default():
+        record_launch("scatter_join", *operands, mode="xla")
+        return _scatter_join_ref_jit(*operands)
+    interpret = bool(interpret)
+    record_launch("scatter_join", *operands, mode=_mode(interpret))
+    return _scatter_join_jit(*operands, interpret=interpret)
 
 
 # re-export the oracles for convenience

@@ -58,8 +58,9 @@ VVIEW = "_resident_cache"      # attribute slot on LatticeStore objects
 class ResidentColumns:
     """One signature group's device-resident stacked columns + digest.
 
-    ``vals [rows, chunk]`` / ``vers [rows]`` are the chunk data,
-    ``maxabs`` / ``sumsq`` ``[rows] f32`` the per-chunk digest columns
+    ``vals [rows, chunk]`` / ``vers [rows]`` are the chunk data (``rows``
+    padded to whole ``ops.ROW_TILE`` blocks by ⊥ rows no layout span
+    covers), ``maxabs`` / ``sumsq`` ``[rows] f32`` the per-chunk digest columns
     (always fresh: every join kernel writes them alongside the merge).
     ``layout`` / ``sig`` / ``spans`` mirror the host ``_StackedChunks``
     bookkeeping; ``vers_host`` is a host copy of the version column kept
@@ -94,6 +95,16 @@ def resident_of(store) -> Optional[ResidentColumns]:
     return store.__dict__.get(VVIEW)
 
 
+def _padded(x: np.ndarray) -> np.ndarray:
+    """A host column padded to whole ``ROW_TILE`` blocks with ⊥ rows
+    (version 0, zero values), so every kernel launch over the resident
+    columns views them without a copy."""
+    pad = ops.padded_rows(x.shape[0]) - x.shape[0]
+    if pad:
+        x = np.concatenate([x, np.zeros((pad,) + x.shape[1:], x.dtype)])
+    return x
+
+
 def _upload(x: np.ndarray) -> jax.Array:
     ops.counters.count_h2d(x)
     return jnp.asarray(x)
@@ -108,8 +119,8 @@ def _stack_densified(store):
     not tensor-only / signature-uniform / non-empty."""
     from ..core.store import _StackedChunks, _tensorstate_cls
     ts_cls = _tensorstate_cls()
-    if (ts_cls is None or not store.entries
-            or not all(isinstance(v, ts_cls) for _, v in store.entries)):
+    if not store.entries or not all(isinstance(v, ts_cls)
+                                    for _, v in store.entries):
         return None
     parts_v, parts_r, layout = [], [], []
     chunkw = dtype = vdtype = None
@@ -152,11 +163,12 @@ def adopt(store) -> Optional[ResidentColumns]:
         sa = _stack_densified(store)
     if sa is None:
         return None
-    vals = _upload(sa.vals)
-    vers = _upload(sa.vers)
+    vers_host = _padded(sa.vers)
+    vals = _upload(_padded(sa.vals))
+    vers = _upload(vers_host)
     ma, ss = ops.chunk_digest_auto(vals)
     cache = ResidentColumns(vals, vers, ma, ss, sa.layout, sa.sig,
-                            np.asarray(sa.vers))
+                            vers_host)
     object.__setattr__(store, VVIEW, cache)
     return cache
 
@@ -175,8 +187,10 @@ def spill(store):
     if cache is None:
         return None
     from ..core.store import _StackedChunks
-    ops.counters.count_d2h(cache.vals, cache.vers)
-    sc = _StackedChunks(np.asarray(cache.vals), np.asarray(cache.vers),
+    rows = cache.layout[-1][3]          # the ⊥ pad rows stay on device
+    vals, vers = cache.vals[:rows], cache.vers[:rows]
+    ops.counters.count_d2h(vals, vers)
+    sc = _StackedChunks(np.asarray(vals), np.asarray(vers),
                         cache.layout, cache.sig)
     object.__setattr__(store, "_stacked_cache", sc)
     return sc

@@ -50,6 +50,7 @@ from repro.core import (AWORSet, Compose, MVRegister, NetConfig, ORMap,
                         POLICY_SPECS, Replica, Simulator, StoreReplica,
                         causal_policy_spec, converged, make_policy,
                         run_to_convergence)
+from repro.launch.cache import enable_compile_cache
 from repro.models import decode_step, init_model, prefill
 
 
@@ -130,6 +131,7 @@ def main() -> None:
                          "/metrics.json; --status-file heartbeats gain "
                          "the full snapshot")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.listen or args.peers:
         from repro.net import validate_net_args

@@ -12,10 +12,9 @@ Two modes (DESIGN.md §2):
   pseudo-gradient deltas over a lossy simulated network (loss/dup/reorder
   configurable); convergence is Prop. 1, not exactly-once delivery.
 
-Defaults are smoke-scale; ``--arch qwen1.5-0.5b --steps 300 --seq 256``
-exercises a ~0.5B-param model for a few hundred steps on CPU (the
-assignment's end-to-end driver; see examples/train_delta_sync.py for the
-scripted version)."""
+Both modes run the published widths of ``--arch``; ``--reduced`` swaps in
+the same family's tiny smoke config (see examples/train_delta_sync.py for
+a scripted version)."""
 
 from __future__ import annotations
 
@@ -35,6 +34,7 @@ from repro.core import (NetConfig, POLICY_SPECS, Simulator,
                         causal_policy_spec, converged, make_policy,
                         run_to_convergence)
 from repro.data import SyntheticLMStream
+from repro.launch.cache import enable_compile_cache
 from repro.models import init_model, train_loss
 from repro.optim import AdamWConfig
 from repro.runtime import TrainConfig, make_train_step
@@ -56,7 +56,7 @@ def run_sync(args) -> None:
     tcfg = TrainConfig(optimizer=AdamWConfig(
         lr=args.lr, warmup_steps=max(10, args.steps // 20),
         total_steps=args.steps))
-    step_fn = jax.jit(make_train_step(cfg, tcfg))
+    step_fn = jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0, 1))
 
     store = DeltaCheckpointStore(args.ckpt_dir) if args.ckpt_dir else None
     start_step = 0
@@ -94,29 +94,40 @@ def run_sync(args) -> None:
     print(f"[done] {args.steps} steps in {time.time() - t0:.1f}s")
 
 
-def run_delta(args) -> None:
-    cfg = get_config(args.arch, reduced=True)  # delta demo is smoke-scale
-    stream = SyntheticLMStream(vocab=cfg.vocab, seq=args.seq,
-                               batch=args.batch, seed=args.seed)
-    init_params = _init(cfg, args.seed)
+def make_delta_step(cfg, args):
+    """The jitted inner step of ``--mode delta``. It donates params and
+    optimizer state, so a caller keeps neither after a step."""
     tcfg = TrainConfig(optimizer=AdamWConfig(lr=args.lr,
                                              warmup_steps=5,
                                              total_steps=args.steps))
+    return jax.jit(make_train_step(cfg, tcfg), donate_argnums=(0, 1))
+
+
+def run_delta(args):
+    """Train ``--pods`` delta-synced pods; returns ``(pods, losses)``
+    with every local step's loss, in order."""
+    cfg = get_config(args.arch, reduced=args.reduced)
+    stream = SyntheticLMStream(vocab=cfg.vocab, seq=args.seq,
+                               batch=args.batch, seed=args.seed)
+    init_params = _init(cfg, args.seed)
     from repro.optim.adamw import init_opt_state
-    step_jit = jax.jit(make_train_step(cfg, tcfg))
+    step_jit = make_delta_step(cfg, args)
+    losses = []
 
     def local_update(params, round_idx, pod_id):
         # K local steps on this pod's data shard (fresh opt state per round
-        # — pseudo-gradient outer loop)
+        # — pseudo-gradient outer loop); the pod keeps ``params``, so the
+        # donating step starts from a copy
         opt = init_opt_state(params)
         rank = int(pod_id.split("pod")[-1])
-        p = params
+        p = jax.tree_util.tree_map(jnp.copy, params)
         for k in range(args.local_steps):
             b = stream.batch_at(round_idx * args.local_steps + k, rank=rank)
             p, opt, m = step_jit(p, opt, {k2: jnp.asarray(v)
                                           for k2, v in b.items()})
+            losses.append(float(m["loss"]))
         print(f"  [{pod_id}] round {round_idx} loss "
-              f"{float(m['loss']):.4f}", flush=True)
+              f"{losses[-1]:.4f}", flush=True)
         return p
 
     sim = Simulator(NetConfig(loss=args.net_loss, dup=0.1, seed=args.seed))
@@ -143,6 +154,7 @@ def run_delta(args) -> None:
           f"ship-policy={policy_spec}, payload_atoms={payload}); "
           f"all pods converged to identical outer params "
           f"({len(pods[0].X.dots)} dots merged)")
+    return pods, losses
 
 
 def main() -> None:
@@ -178,6 +190,7 @@ def main() -> None:
                     help="delta-mode gossip shipping policy "
                          f"(e.g. {', '.join(POLICY_SPECS)})")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "sync":
         run_sync(args)
     else:

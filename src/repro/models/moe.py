@@ -37,23 +37,10 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from .hints import current_rules
-
-try:  # jax >= 0.6 moved shard_map around
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-# jax >= 0.6 renamed the replication-check kwarg check_rep → check_vma;
-# accept either runtime.
-_SHARD_MAP_CHECK_KW = (
-    "check_vma" if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep")
-
-from jax.sharding import PartitionSpec as P
 
 
 def init_moe(cfg, key, dtype) -> Tuple[Dict, Dict]:
@@ -287,9 +274,8 @@ def _apply_moe_local(p: Dict, cfg, x: jax.Array, ctx
                 P(None, None), wi_spec,
                 (wi_spec if "wg" in p else None), wo_spec)
     out_specs = (P(dp if len(dp) > 1 else dp[0], None, None), P())
-    y, aux = _shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs,
-                        **{_SHARD_MAP_CHECK_KW: False})(
+    y, aux = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)(
         x, p["router"], p["wi"], p.get("wg"), p["wo"])
 
     if m.num_shared_experts:

@@ -41,7 +41,8 @@ def lr_at_step(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
 
 
 def init_opt_state(params: Any) -> Dict[str, Any]:
-    f32 = lambda x: x.astype(jnp.float32)
+    # a fresh buffer even for f32 params: train steps donate the state
+    f32 = lambda x: jnp.array(x, dtype=jnp.float32)
     return {
         "m": jax.tree_util.tree_map(lambda x: jnp.zeros(x.shape, jnp.float32),
                                     params),

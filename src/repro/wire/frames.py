@@ -320,10 +320,7 @@ class WireCodec:
 
     @staticmethod
     def _payload_kind(value: Any, full_state: bool) -> str:
-        try:
-            from ..sync.membership import ClusterState
-        except Exception:  # pragma: no cover - partial installs
-            ClusterState = ()  # type: ignore[assignment]
+        from ..sync.membership import ClusterState
         if isinstance(value, ClusterState):
             return "membership"
         return "state" if full_state else "delta"

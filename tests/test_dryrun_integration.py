@@ -19,6 +19,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.shapes import ShapeCase
     from repro.dist import make_rules
     from repro.launch.dryrun import _cell_costs, _lower_and_compile
+    from repro.launch.mesh import make_mesh
 
     arch = os.environ["TEST_ARCH"]
     step = os.environ["TEST_STEP"]
@@ -29,7 +30,7 @@ SCRIPT = textwrap.dedent("""
     if cfg.input_mode == "tokens+prefix":
         seq = max(seq, cfg.prefix_len + 16)
     case = ShapeCase("t", seq, 8, step)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules(mesh)
     lowered, compiled = _lower_and_compile(cfg, case, mesh, False, rules)
     costs = _cell_costs(compiled, 8)

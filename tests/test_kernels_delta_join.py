@@ -46,7 +46,7 @@ else:
 
 
 @_property
-def test_delta_join_kernel_is_a_join(seed=0):
+def test_delta_join_kernel_is_a_join(seed):
     """Kernel-level lattice laws: idempotent / commutative / associative.
     (Ties must carry equal values, as the TensorState lattice guarantees.)"""
     rng = np.random.default_rng(seed)
@@ -276,3 +276,34 @@ def test_counters_count_launches_and_numpy_staging_only():
     ops.fused_join_digest(av, avers, av, avers)
     d = ops.counters.since(snap)
     assert d["launches"] == 1 and d["h2d_bytes"] == 0
+
+
+def test_scatter_join_merges_rows_sharing_a_block():
+    """Unsorted delta rows packed into a few 8-row value blocks and one
+    1024-row column block: every row's merge survives its block-mates'."""
+    rng = np.random.default_rng(45)
+    n, chunk = 3000, 128
+    vals = jnp.asarray(rng.normal(size=(n, chunk)).astype(np.float32))
+    vers = jnp.asarray(rng.integers(0, 50, size=(n,)).astype(np.int32))
+    ma, ss = ops.chunk_digest_ref(vals)
+    idx = rng.permutation(np.r_[np.arange(1000, 1024), np.arange(8, 24),
+                                [2999]]).astype(np.int32)
+    d_vals = jnp.asarray(rng.normal(size=(idx.size, chunk))
+                         .astype(np.float32))
+    d_vers = jnp.asarray(rng.integers(0, 80, size=(idx.size,))
+                         .astype(np.int32))
+    args = (vals, vers, ma, ss, jnp.asarray(idx), d_vals, d_vers)
+    for x, y in zip(ops.scatter_join(*args, interpret=True),
+                    ops.scatter_join_ref(*args)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5)
+
+
+def test_scatter_join_splits_long_deltas_across_launches(monkeypatch):
+    """More delta rows than one launch's SMEM holds: consecutive launches
+    chain over the aliased columns and give the one-launch answer."""
+    from repro.kernels import delta_join as dj
+    args = _mk_scatter(64, 40, 128, 46)
+    monkeypatch.setattr(dj, "_SCATTER_ROWS", 16)
+    outs = dj.scatter_join(*args, interpret=True)
+    for x, y in zip(outs, ops.scatter_join_ref(*args)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-5)

@@ -30,7 +30,8 @@ SCRIPT = textwrap.dedent("""
                                   capacity_factor=float(E)),  # dropless
                       dtype="float32", moe_impl="local")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = {"tokens": "data", "batch": "data"}
     p, _ = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32), jnp.float32)
