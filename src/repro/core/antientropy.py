@@ -42,6 +42,7 @@ from __future__ import annotations
 import random
 from typing import Any, Callable, Optional, Sequence
 
+from ..obs.trace import span
 from .propagation import (Replica, ShipAll, ShippingPolicy,
                           ShipStateEveryK)
 from .sim import Node, Simulator
@@ -163,8 +164,9 @@ class FullStateNode(Node):
 
 
 def converged(nodes: Sequence[Node]) -> bool:
-    states = [n.X for n in nodes]
-    return all(s == states[0] for s in states[1:])
+    with span("antientropy.converged"):
+        states = [n.X for n in nodes]
+        return all(s == states[0] for s in states[1:])
 
 
 def run_to_convergence(sim: Simulator, nodes: Sequence[Node],

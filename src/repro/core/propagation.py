@@ -64,6 +64,7 @@ import zlib
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, FrozenSet, List, Optional, Sequence
 
+from ..obs.trace import span
 from .digest import StoreDigest, digest_diff, store_digest
 from .sim import Node, structural_size
 from .store import LatticeStore
@@ -694,40 +695,45 @@ class Replica(Node):
     def on_periodic(self) -> None:
         if not self.alive or not self.neighbors:
             return
-        self.rounds += 1
-        if self.resident:
-            # (re-)adopt the durable store into resident device columns;
-            # a no-op when the current store already carries the cache
-            from ..kernels import resident as _resident
-            _resident.ensure(self.store)
-        # the policy picks which neighbors this broadcast addresses at
-        # all (hierarchical gossip: intra-zone peers + relay links);
-        # fanout sampling applies within that set
-        targets = self.policy.targets(self, list(self.neighbors))
-        if self.fanout is not None and self.fanout < len(targets):
-            targets = self.rng.sample(targets, k=self.fanout)
-        self._group_cache = None     # per-broadcast joined delta-group
-        self._digest_cache = None    # per-broadcast encoded digest
-        for j in targets:
-            # pull vs push is per destination: a relay pushes intervals
-            # to its own zone and trades digests across zones in the
-            # same round (flat pull policies ignore dst — unchanged)
-            if self.policy.pull_exchange and self.policy.pull_round(self, j):
-                self._ship_digest(j)
-            else:
-                self._ship_to(j)
-        if self.policy.pure_pull:
-            # state-driven exchange: digest responses are computed from X,
-            # never from the buffer, and no pushes means no acks to GC it
-            self.entries = {}
-        elif not self.causal:
-            # Algorithm 1: an entry leaves D only once broadcast to every
-            # neighbor (per-destination watermarks — see _basic_sent)
-            self._gc_shipped_basic()
-        if self.reaper is not None:
-            # key lifecycle rides the same cadence: propose/retransmit/
-            # commit reaps after the round's payloads are out
-            self.reaper.step()
+        with span("engine.periodic"):
+            self.rounds += 1
+            if self.resident:
+                # (re-)adopt the durable store into resident device
+                # columns; a no-op when the current store already carries
+                # the cache
+                from ..kernels import resident as _resident
+                _resident.ensure(self.store)
+            # the policy picks which neighbors this broadcast addresses at
+            # all (hierarchical gossip: intra-zone peers + relay links);
+            # fanout sampling applies within that set
+            targets = self.policy.targets(self, list(self.neighbors))
+            if self.fanout is not None and self.fanout < len(targets):
+                targets = self.rng.sample(targets, k=self.fanout)
+            self._group_cache = None     # per-broadcast joined delta-group
+            self._digest_cache = None    # per-broadcast encoded digest
+            for j in targets:
+                # pull vs push is per destination: a relay pushes intervals
+                # to its own zone and trades digests across zones in the
+                # same round (flat pull policies ignore dst — unchanged)
+                if (self.policy.pull_exchange
+                        and self.policy.pull_round(self, j)):
+                    self._ship_digest(j)
+                else:
+                    self._ship_to(j)
+            if self.policy.pure_pull:
+                # state-driven exchange: digest responses are computed from
+                # X, never from the buffer, and no pushes means no acks to
+                # GC it
+                self.entries = {}
+            elif not self.causal:
+                # Algorithm 1: an entry leaves D only once broadcast to
+                # every neighbor (per-destination watermarks — see
+                # _basic_sent)
+                self._gc_shipped_basic()
+            if self.reaper is not None:
+                # key lifecycle rides the same cadence: propose/retransmit/
+                # commit reaps after the round's payloads are out
+                self.reaper.step()
 
     # -- paper: chooseᵢ(Xᵢ, Dᵢ) + ship ------------------------------------------
     def _ship_to(self, j: str) -> None:
@@ -940,52 +946,54 @@ class Replica(Node):
 
     # -- paper: on receiveⱼ,ᵢ ---------------------------------------------------
     def on_receive(self, src: str, msg: Any) -> None:
-        if self.tracer is not None:
-            self._rx_bytes = (len(msg)
-                              if isinstance(msg, (bytes, bytearray))
-                              else structural_size(msg))
-        if self.wire is not None and isinstance(msg, (bytes, bytearray)):
-            msg = self.wire.decode_msg(msg)
-        kind = msg[0]
-        if kind == "handoff":
-            self._receive_handoff(src, msg[1])
-            return
-        if kind == "reap":
-            # vote on another owner's reap proposal from local lifecycle
-            # state — every keyed replica can vote, reaper attached or not
-            _, key, epoch, expiry = msg
-            self._post(src, ("reap-ack", key, epoch, expiry,
-                             int(self._reap_agree(key, epoch, expiry))))
-            return
-        if kind == "reap-ack":
+        with span("engine.receive"):
             if self.tracer is not None:
-                self.tracer.emit("reap_ack", src=src, key=msg[1],
-                                 ok=bool(msg[4]), round=self.rounds)
-            if self.reaper is not None:
-                self.reaper.on_ack(src, msg)
-            return
-        if kind == "digest":
-            self._receive_digest_request(src, msg[1])
-            return
-        if kind == "digest-resp":
-            self._receive_digest_response(src, msg[1])
-            return
-        if not self.causal:
-            _, d = msg
-            before = self.X if self.tracer is not None else None
-            self.X = self.X.join(d)
-            if self.transitive:
-                self._record(d, origin=src)
-            if self.tracer is not None:
-                self._trace_join(src, d, "delta", self._rx_bytes, before)
-            return
-        if kind == "delta":
-            _, d, n, ghost = msg
-            self._receive_delta(src, d, n, ghost)
-        elif kind == "ack":
-            self._receive_ack(src, msg[1])
-        else:  # pragma: no cover
-            raise ValueError(f"unknown message kind {kind!r}")
+                self._rx_bytes = (len(msg)
+                                  if isinstance(msg, (bytes, bytearray))
+                                  else structural_size(msg))
+            if self.wire is not None and isinstance(msg, (bytes, bytearray)):
+                msg = self.wire.decode_msg(msg)
+            kind = msg[0]
+            if kind == "handoff":
+                self._receive_handoff(src, msg[1])
+                return
+            if kind == "reap":
+                # vote on another owner's reap proposal from local
+                # lifecycle state — every keyed replica can vote, reaper
+                # attached or not
+                _, key, epoch, expiry = msg
+                self._post(src, ("reap-ack", key, epoch, expiry,
+                                 int(self._reap_agree(key, epoch, expiry))))
+                return
+            if kind == "reap-ack":
+                if self.tracer is not None:
+                    self.tracer.emit("reap_ack", src=src, key=msg[1],
+                                     ok=bool(msg[4]), round=self.rounds)
+                if self.reaper is not None:
+                    self.reaper.on_ack(src, msg)
+                return
+            if kind == "digest":
+                self._receive_digest_request(src, msg[1])
+                return
+            if kind == "digest-resp":
+                self._receive_digest_response(src, msg[1])
+                return
+            if not self.causal:
+                _, d = msg
+                before = self.X if self.tracer is not None else None
+                self.X = self.X.join(d)
+                if self.transitive:
+                    self._record(d, origin=src)
+                if self.tracer is not None:
+                    self._trace_join(src, d, "delta", self._rx_bytes, before)
+                return
+            if kind == "delta":
+                _, d, n, ghost = msg
+                self._receive_delta(src, d, n, ghost)
+            elif kind == "ack":
+                self._receive_ack(src, msg[1])
+            else:  # pragma: no cover
+                raise ValueError(f"unknown message kind {kind!r}")
 
     def _receive_delta(self, src: str, d: Any, n: int,
                        ghost: Any = None) -> None:
@@ -1037,25 +1045,26 @@ class Replica(Node):
 
     # -- paper: periodically (garbage collect deltas) ----------------------------
     def gc_deltas(self) -> None:
-        self.prune_departed()
-        if not self.entries:
-            return
-        # The horizon ranges over *push* peers only: a peer the policy
-        # reaches by digest pull alone never acks, so waiting on it
-        # would pin the buffer forever. With no push peers at all the
-        # buffer is useless — clear it (X holds the state; digest-sync
-        # is the repair path, Def. 6 keeps that join-equivalent).
-        peers = self.policy.ack_peers(self, list(self.neighbors))
-        horizon = (min(self.A.get(j, 0) for j in peers)
-                   if peers else self.c)
-        depth = len(self.entries)
-        self.entries = {n: e for n, e in self.entries.items()
-                        if n >= horizon}
-        dropped = depth - len(self.entries)
-        if dropped and self.tracer is not None:
-            self.tracer.emit("gc_horizon_advance", horizon=horizon,
-                             dropped=dropped, depth=len(self.entries),
-                             round=self.rounds)
+        with span("engine.gc_deltas"):
+            self.prune_departed()
+            if not self.entries:
+                return
+            # The horizon ranges over *push* peers only: a peer the policy
+            # reaches by digest pull alone never acks, so waiting on it
+            # would pin the buffer forever. With no push peers at all the
+            # buffer is useless — clear it (X holds the state; digest-sync
+            # is the repair path, Def. 6 keeps that join-equivalent).
+            peers = self.policy.ack_peers(self, list(self.neighbors))
+            horizon = (min(self.A.get(j, 0) for j in peers)
+                       if peers else self.c)
+            depth = len(self.entries)
+            self.entries = {n: e for n, e in self.entries.items()
+                            if n >= horizon}
+            dropped = depth - len(self.entries)
+            if dropped and self.tracer is not None:
+                self.tracer.emit("gc_horizon_advance", horizon=horizon,
+                                 dropped=dropped, depth=len(self.entries),
+                                 round=self.rounds)
 
     def prune_departed(self) -> None:
         """Drop ALL per-peer bookkeeping for peers no longer in
@@ -1192,7 +1201,8 @@ class StoreReplica(Replica):
             S, key, LatticeStore.key_delta(key, delta_value)))
 
     def get(self, key: str, typ=None):
-        return self.X.get(key, typ)
+        with span("store.get"):
+            return self.X.get(key, typ)
 
     def keys(self):
         return self.X.keys()

@@ -54,6 +54,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, FrozenSet, Iterable, List, Mapping, Tuple
 
 from ..lifecycle.lattice import LIFE_BOTTOM, Life, life_join
+from ..obs.trace import span
 
 
 def _is_bottom(value: Any) -> bool:
@@ -172,6 +173,13 @@ class LatticeStore:
 
     def join(self, other: "LatticeStore", *,
              batched: bool = True) -> "LatticeStore":
+        if self.__dict__.get("_resident_cache") is None:
+            return self._join(other, batched)
+        with span("resident.join"):
+            return self._join(other, batched)
+
+    def _join(self, other: "LatticeStore",
+              batched: bool) -> "LatticeStore":
         life = _joined_life(self.life, other.life)
         if batched and self._epochs() == other._epochs():
             # identical epochs per key ⇒ every value joins pointwise, so

@@ -216,8 +216,19 @@ def _join_sparse_sparse(a: SparseChunks, b: SparseChunks) -> SparseChunks:
     return SparseChunks(a.n_chunks, idx[last], vals[last], vers[last])
 
 
+def _values_dtype(ct):
+    return ct.vals.dtype if ct.is_sparse else ct.values.dtype
+
+
 def _pair_join(a, b):
-    """Join two chunk tensors of any density mix."""
+    """Join two chunk tensors of any density mix. Both must hold the
+    same ``[n_chunks, chunk]`` layout and value dtype: any other pair is
+    a type error in every density mix (the dense path would broadcast,
+    the sparse paths index out of range)."""
+    if a.shape != b.shape or _values_dtype(a) != _values_dtype(b):
+        raise ValueError(
+            f"cannot join chunk tensors of layout {a.shape} "
+            f"{_values_dtype(a)} and {b.shape} {_values_dtype(b)}")
     if not a.is_sparse and not b.is_sparse:
         v, vers = _join_chunked(a.values, a.versions, b.values, b.versions)
         return ChunkedTensor(v, vers)

@@ -25,7 +25,7 @@ device. Benchmarks snapshot/diff the counters around each round.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,31 +109,18 @@ class KernelCounters:
 
 counters = KernelCounters()
 
-# optional process-wide launch observer (repro.obs.trace installs one):
-# called (op_name, h2d_bytes_this_launch) after the counters update
-_launch_hook: Optional[Callable[[str, int], None]] = None
-
-
-def set_launch_hook(fn: Optional[Callable[[str, int], None]]) -> None:
-    """Install (or clear, with None) the process-wide launch observer."""
-    global _launch_hook
-    _launch_hook = fn
-
 
 def record_launch(name: str, *operands, mode: str = "xla") -> None:
-    """Account one named kernel dispatch: bump the counters and notify
-    the launch hook. Every wrapper (and any out-of-module launch site,
-    e.g. the resident store's ranking epilogue) routes through here so
-    launches are observable by name, not just as a bare count. ``mode``
-    says what ran: ``"compiled"`` (a Mosaic kernel), ``"interpret"``
-    (Pallas interpret mode) or ``"xla"`` (a jitted XLA program)."""
+    """Account one named kernel dispatch in the counters. Every wrapper
+    (and any out-of-module launch site, e.g. the resident store's ranking
+    epilogue) routes through here so launches are counted by name, not
+    just as a bare count. ``mode`` says what ran: ``"compiled"`` (a
+    Mosaic kernel), ``"interpret"`` (Pallas interpret mode) or ``"xla"``
+    (a jitted XLA program)."""
     counters.launches += 1
     key = f"{name}:{mode}"
     counters.by_kernel[key] = counters.by_kernel.get(key, 0) + 1
-    before = counters.h2d_bytes
     counters.count_h2d(*operands)
-    if _launch_hook is not None:
-        _launch_hook(name, counters.h2d_bytes - before)
 
 
 def _mode(interpret: bool) -> str:
@@ -180,8 +167,16 @@ def flash_decode(q, k, v, q_pos, k_pos, *, scale: Optional[float] = None,
 # δ-CRDT joins and digests
 # ---------------------------------------------------------------------------
 
+def _store_kernel(fn):
+    """``fn`` traced under ``jax.named_scope("store.<name>")``, so its
+    device operations carry the kernel's name in the profiler trace; the
+    jitted module keeps ``fn``'s own name (``jit_<name>``)."""
+    return jax.named_scope(f"store.{fn.__name__}")(fn)
+
+
 _delta_join_jit = functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret"))(_delta_join)
+    jax.jit, static_argnames=("block_n", "interpret"))(
+        _store_kernel(_delta_join))
 
 
 def delta_join(a_vals, a_vers, b_vals, b_vers, *, block_n: int = ROW_TILE,
@@ -211,8 +206,9 @@ def batched_delta_join(segments, *, block_n: int = ROW_TILE,
 
 
 _chunk_digest_jit = functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret"))(_chunk_digest)
-_chunk_digest_ref_jit = jax.jit(ref.chunk_digest_ref)
+    jax.jit, static_argnames=("block_n", "interpret"))(
+        _store_kernel(_chunk_digest))
+_chunk_digest_ref_jit = jax.jit(_store_kernel(ref.chunk_digest_ref))
 
 
 def chunk_digest(x, *, block_n: int = ROW_TILE,
@@ -236,8 +232,10 @@ def chunk_digest_auto(x, *, block_n: int = ROW_TILE
 
 
 _fused_join_digest_jit = functools.partial(
-    jax.jit, static_argnames=("block_n", "interpret"))(_fused_join_digest)
-_fused_join_digest_ref_jit = jax.jit(ref.fused_join_digest_ref)
+    jax.jit, static_argnames=("block_n", "interpret"))(
+        _store_kernel(_fused_join_digest))
+_fused_join_digest_ref_jit = jax.jit(
+    _store_kernel(ref.fused_join_digest_ref))
 
 
 def fused_join_digest(a_vals, a_vers, b_vals, b_vers, *,
@@ -259,8 +257,8 @@ def fused_join_digest(a_vals, a_vers, b_vals, b_vers, *,
 
 
 _scatter_join_jit = functools.partial(
-    jax.jit, static_argnames=("interpret",))(_scatter_join)
-_scatter_join_ref_jit = jax.jit(ref.scatter_join_ref)
+    jax.jit, static_argnames=("interpret",))(_store_kernel(_scatter_join))
+_scatter_join_ref_jit = jax.jit(_store_kernel(ref.scatter_join_ref))
 
 
 def scatter_join(vals, vers, maxabs, sumsq, idx, d_vals, d_vers, *,

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span
 from . import ops
 
 VVIEW = "_resident_cache"      # attribute slot on LatticeStore objects
@@ -212,7 +213,8 @@ def try_join(a_store, b_store, life):
     rb = resident_of(b_store)
     if rb is not None and rb.sig == ra.sig:
         return _aligned_join(ra, rb, a_store, b_store, life)
-    plan = _scatter_plan(ra, b_store)
+    with span("resident.plan"):
+        plan = _scatter_plan(ra, b_store)
     if plan is None:
         return None
     return _scatter_ingest(ra, a_store, b_store, life, plan)
@@ -222,25 +224,28 @@ def _aligned_join(ra: ResidentColumns, rb: ResidentColumns,
                   a_store, b_store, life):
     """Two resident stores with the identical stacked layout: the whole
     join (and the next round's digest) is ONE fused launch."""
-    ov, over, ma, ss = ops.fused_join_digest(ra.vals, ra.vers,
-                                             rb.vals, rb.vers)
-    entries, li = [], 0
-    from ..core.tensor_lattice import ChunkedTensor, TensorState
-    for (key, A), (_, B) in zip(a_store.entries, b_store.entries):
-        chunks = []
-        for name, _ct in A.chunks:
-            _, _, start, stop = ra.layout[li]
-            li += 1
-            chunks.append((name, ChunkedTensor(ov[start:stop],
-                                               over[start:stop])))
-        entries.append((key, TensorState(tuple(chunks),
-                                         max(A.lamport, B.lamport))))
     from ..core.store import LatticeStore
-    result = LatticeStore(tuple(entries), life)
-    cache = ResidentColumns(ov, over, ma, ss, ra.layout, ra.sig,
-                            np.maximum(ra.vers_host, rb.vers_host),
-                            ra.spans)
-    object.__setattr__(result, VVIEW, cache)
+    from ..core.tensor_lattice import ChunkedTensor, TensorState
+
+    with span("resident.dispatch"):
+        ov, over, ma, ss = ops.fused_join_digest(ra.vals, ra.vers,
+                                                 rb.vals, rb.vers)
+    with span("resident.rebuild"):
+        entries, li = [], 0
+        for (key, A), (_, B) in zip(a_store.entries, b_store.entries):
+            chunks = []
+            for name, _ct in A.chunks:
+                _, _, start, stop = ra.layout[li]
+                li += 1
+                chunks.append((name, ChunkedTensor(ov[start:stop],
+                                                   over[start:stop])))
+            entries.append((key, TensorState(tuple(chunks),
+                                             max(A.lamport, B.lamport))))
+        result = LatticeStore(tuple(entries), life)
+        cache = ResidentColumns(ov, over, ma, ss, ra.layout, ra.sig,
+                                np.maximum(ra.vers_host, rb.vers_host),
+                                ra.spans)
+        object.__setattr__(result, VVIEW, cache)
     return result
 
 
@@ -371,42 +376,44 @@ def _scatter_ingest(ra: ResidentColumns, a_store, b_store, life, plan):
                 d_vals = jnp.concatenate([d_vals, zpad_v], axis=0)
                 d_vers = jnp.concatenate([d_vers, zpad_r])
 
-    ov, over, ma, ss = ops.scatter_join(ra.vals, ra.vers, ra.maxabs,
-                                        ra.sumsq, idx, d_vals, d_vers)
+    with span("resident.dispatch"):
+        ov, over, ma, ss = ops.scatter_join(ra.vals, ra.vers, ra.maxabs,
+                                            ra.sumsq, idx, d_vals, d_vers)
 
-    # host mirror of the version column: O(r) numpy, no device read
-    if r:
-        vh = ra.vers_host.copy()
-        real_idx = idx[:r]
-        if d_vers_host is None:
-            d_vers_host = np.asarray(d_vers)[:r]
-            ops.counters.count_d2h(d_vers_host)
-        take = d_vers_host[:r] > vh[real_idx]
-        vh[real_idx[take]] = d_vers_host[:r][take]
-    else:
-        vh = ra.vers_host
+    with span("resident.rebuild"):
+        # host mirror of the version column: O(r) numpy, no device read
+        if r:
+            vh = ra.vers_host.copy()
+            real_idx = idx[:r]
+            if d_vers_host is None:
+                d_vers_host = np.asarray(d_vers)[:r]
+                ops.counters.count_d2h(d_vers_host)
+            take = d_vers_host[:r] > vh[real_idx]
+            vh[real_idx[take]] = d_vers_host[:r][take]
+        else:
+            vh = ra.vers_host
 
-    touched: Dict[str, Any] = {}
-    a_map = dict(a_store.entries)
-    for key, B in b_store.entries:
-        A = a_map[key]
-        b_names = frozenset(n for n, _ in B.chunks)
-        chunks = []
-        for name, ct in A.chunks:
-            if name in b_names:
-                start, stop = ra.spans[(key, name)]
-                chunks.append((name, ChunkedTensor(ov[start:stop],
-                                                   over[start:stop])))
-            else:
-                chunks.append((name, ct))
-        touched[key] = TensorState(tuple(chunks),
-                                   max(A.lamport, B.lamport))
+        touched: Dict[str, Any] = {}
+        a_map = dict(a_store.entries)
+        for key, B in b_store.entries:
+            A = a_map[key]
+            b_names = frozenset(n for n, _ in B.chunks)
+            chunks = []
+            for name, ct in A.chunks:
+                if name in b_names:
+                    start, stop = ra.spans[(key, name)]
+                    chunks.append((name, ChunkedTensor(ov[start:stop],
+                                                       over[start:stop])))
+                else:
+                    chunks.append((name, ct))
+            touched[key] = TensorState(tuple(chunks),
+                                       max(A.lamport, B.lamport))
 
-    entries = tuple((k, touched.get(k, v)) for k, v in a_store.entries)
-    result = LatticeStore(entries, life)
-    cache = ResidentColumns(ov, over, ma, ss, ra.layout, ra.sig, vh,
-                            ra.spans)
-    object.__setattr__(result, VVIEW, cache)
+        entries = tuple((k, touched.get(k, v)) for k, v in a_store.entries)
+        result = LatticeStore(entries, life)
+        cache = ResidentColumns(ov, over, ma, ss, ra.layout, ra.sig, vh,
+                                ra.spans)
+        object.__setattr__(result, VVIEW, cache)
     return result
 
 

@@ -122,28 +122,29 @@ def _apply_block(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
     x = hint(x, ("batch", None, None))
     h = apply_norm(p["norm1"], x, cfg.norm)
     new_cache = None
-    if spec.kind == "attn":
-        if mode == "decode":
-            y, new_cache = attn_mod.attend_decode(p["mix"], cfg, spec, h,
+    with jax.named_scope("ssm" if spec.kind == "ssm" else "attention"):
+        if spec.kind == "attn":
+            if mode == "decode":
+                y, new_cache = attn_mod.attend_decode(p["mix"], cfg, spec,
+                                                      h, positions, cache)
+            else:
+                y, new_cache = attn_mod.attend_full(
+                    p["mix"], cfg, spec, h, positions,
+                    make_cache=cache_capacity if mode == "prefill" else None)
+        elif spec.kind == "mla":
+            if mode == "decode":
+                y, new_cache = mla_mod.mla_decode(p["mix"], cfg, spec, h,
                                                   positions, cache)
-        else:
-            y, new_cache = attn_mod.attend_full(
-                p["mix"], cfg, spec, h, positions,
-                make_cache=cache_capacity if mode == "prefill" else None)
-    elif spec.kind == "mla":
-        if mode == "decode":
-            y, new_cache = mla_mod.mla_decode(p["mix"], cfg, spec, h,
-                                              positions, cache)
-        else:
-            y, new_cache = mla_mod.mla_full(
-                p["mix"], cfg, spec, h, positions,
-                make_cache=cache_capacity if mode == "prefill" else None)
-    else:  # ssm
-        if mode == "decode":
-            y, new_cache = ssm_mod.ssm_decode(p["mix"], cfg, h, cache)
-        else:
-            y, new_cache = ssm_mod.ssm_full(p["mix"], cfg, h,
-                                            make_cache=(mode == "prefill"))
+            else:
+                y, new_cache = mla_mod.mla_full(
+                    p["mix"], cfg, spec, h, positions,
+                    make_cache=cache_capacity if mode == "prefill" else None)
+        else:  # ssm
+            if mode == "decode":
+                y, new_cache = ssm_mod.ssm_decode(p["mix"], cfg, h, cache)
+            else:
+                y, new_cache = ssm_mod.ssm_full(
+                    p["mix"], cfg, h, make_cache=(mode == "prefill"))
     if cfg.post_norms:
         y = apply_norm(p["post_attn"], y, cfg.norm)
     x = x + y
@@ -151,10 +152,11 @@ def _apply_block(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
     if spec.mlp == "none":
         return x, new_cache, aux
     h = apply_norm(p["norm2"], x, cfg.norm)
-    if spec.mlp == "dense":
-        y = apply_mlp(p["mlp"], h, cfg.act)
-    else:
-        y, aux = moe_mod.apply_moe(p["mlp"], cfg, h)
+    with jax.named_scope("mlp" if spec.mlp == "dense" else "moe"):
+        if spec.mlp == "dense":
+            y = apply_mlp(p["mlp"], h, cfg.act)
+        else:
+            y, aux = moe_mod.apply_moe(p["mlp"], cfg, h)
     if cfg.post_norms:
         y = apply_norm(p["post_mlp"], y, cfg.norm)
     return x + y, new_cache, aux
@@ -245,10 +247,14 @@ def _inputs_to_hidden(cfg: ModelConfig, params: Dict, batch: Dict
 def forward(cfg: ModelConfig, params: Dict, batch: Dict,
             remat: bool = True) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence logits (training). Returns (logits, aux_loss)."""
-    x, positions = _inputs_to_hidden(cfg, params, batch)
-    x, _, aux = _run_stack(cfg, params, x, positions, "train", remat=remat)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    return lm_logits(params["embed"], cfg, x), aux
+    with jax.named_scope("embed"):
+        x, positions = _inputs_to_hidden(cfg, params, batch)
+    with jax.named_scope("layers"):
+        x, _, aux = _run_stack(cfg, params, x, positions, "train",
+                               remat=remat)
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        return lm_logits(params["embed"], cfg, x), aux
 
 
 def train_loss(cfg: ModelConfig, params: Dict, batch: Dict,
@@ -257,7 +263,8 @@ def train_loss(cfg: ModelConfig, params: Dict, batch: Dict,
     labels = batch["labels"]
     if cfg.input_mode == "tokens+prefix":
         logits = logits[:, cfg.prefix_len:, :]  # loss on text positions only
-    loss = cross_entropy(logits, labels, batch.get("loss_mask"))
+    with jax.named_scope("loss"):
+        loss = cross_entropy(logits, labels, batch.get("loss_mask"))
     return loss + AUX_LOSS_WEIGHT * aux
 
 

@@ -4,7 +4,8 @@ One subsystem, four surfaces (DESIGN.md §12):
 
 * :mod:`repro.obs.trace`    — :class:`Tracer`, the structured event bus
   every engine layer emits into (deterministic-clock mode makes sim and
-  socket traces comparable).
+  socket traces comparable); :func:`span` and :func:`trace_gc`, profiler
+  annotations on the device trace's clock.
 * :mod:`repro.obs.registry` — :class:`Registry` (counters / gauges /
   histograms with label sets) plus absorbers for the counters the repo
   already keeps (``NetStats``/``LinkStats``/``KernelCounters``) and the
@@ -24,7 +25,7 @@ from .registry import (Counter, Gauge, Histogram, Metrics, MetricRecord,
                        MetricsState, Registry, global_registry,
                        reset_global_registry)
 from .scrape import MetricsServer, parse_prometheus, scrape, scrape_json
-from .trace import EVENT_KINDS, Tracer, merge_events, trace_kernel_launches
+from .trace import EVENT_KINDS, Tracer, merge_events, span, trace_gc
 
 __all__ = [
     "AckLagProbe", "Counter", "EVENT_KINDS", "Gauge", "Histogram",
@@ -33,5 +34,5 @@ __all__ = [
     "global_registry", "load_trace", "marker_lag_histogram",
     "merge_events", "parse_prometheus", "redundancy", "report",
     "reset_global_registry", "scrape", "scrape_json", "semantic_trace",
-    "trace_kernel_launches",
+    "span", "trace_gc",
 ]

@@ -30,8 +30,6 @@ kind                  emitted when
                       (``horizon``, ``dropped``, ``depth``)
 ``queue_drop``        a bounded per-peer send queue shed old frames
                       (``dst``, ``dropped``)
-``kernel_launch``     a kernel wrapper dispatched (``op``, ``h2d_bytes``;
-                      via :func:`trace_kernel_launches`)
 ====================  ========================================================
 
 Every event also carries ``t`` (the tracer's clock), ``seq`` (a per-tracer
@@ -59,20 +57,41 @@ it at 1.0.
 The JSONL sink mirrors every kept event to a file as it is emitted, one
 JSON object per line — the interchange format ``analyze.load_trace``
 reads back.
+
+**Spans.** Events say *what* moved; spans say where the host's time
+went. :func:`span` opens a profiler annotation named ``repro.<name>``
+around one call, so under ``jax.profiler.start_trace`` it lands on the
+host plane on the same clock as the device's operations, and a device
+idle gap can be attributed to the innermost span covering it. Spans on
+one thread nest, and a span's self time is its length less its
+children's. With no profiler running a span costs under a microsecond,
+so the sites carry them unconditionally: they sit at call granularity
+(a join, an encode, a tick), never inside a loop over keys, rows or
+chunks. :func:`trace_gc` does the same for the garbage collector's
+pauses (``repro.python.gc``).
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import random
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+# ``jax.profiler.TraceAnnotation`` is this class with a docstring added;
+# taking it from jaxlib keeps pure-CRDT imports of ``repro.core`` from
+# importing jax
+from jaxlib._profiler import TraceMe as _Annotation
+
+SPAN_PREFIX = "repro."
+GC_SPAN = SPAN_PREFIX + "python.gc"
+
 EVENT_KINDS = frozenset({
     "write", "delta_ship", "delta_join", "ack",
     "digest_req", "digest_resp", "handoff",
     "reap_propose", "reap_ack", "reap_commit",
-    "gc_horizon_advance", "queue_drop", "kernel_launch",
+    "gc_horizon_advance", "queue_drop",
 })
 
 
@@ -177,16 +196,30 @@ def merge_events(*sources: Any) -> List[Dict[str, Any]]:
                                          e.get("seq", 0)))
 
 
-def trace_kernel_launches(tracer: Tracer) -> Callable[[], None]:
-    """Install ``tracer`` as the process-wide kernel-launch hook: every
-    ``kernels.ops`` wrapper dispatch emits a ``kernel_launch`` event
-    (op name + host→device bytes staged). Returns an uninstall callable
-    — the hook is global (the counters it mirrors are process-wide), so
-    callers must remove it when their scope ends."""
-    from ..kernels import ops
+def span(name: str) -> _Annotation:
+    """A context manager that marks one call as the profiler annotation
+    ``repro.<name>``; a no-op when no profiler trace is running."""
+    return _Annotation(SPAN_PREFIX + name)
 
-    def hook(op: str, h2d_bytes: int) -> None:
-        tracer.emit("kernel_launch", op=op, h2d_bytes=h2d_bytes)
 
-    ops.set_launch_hook(hook)
-    return lambda: ops.set_launch_hook(None)
+def trace_gc() -> Callable[[], None]:
+    """Mark every garbage-collector pause as a ``repro.python.gc`` span
+    (a ``gc.callbacks`` pair: open at ``start``, close at ``stop``).
+    Returns the callable that uninstalls it."""
+    open_spans: List[_Annotation] = []
+
+    def on_gc(phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            ann = _Annotation(GC_SPAN)
+            ann.__enter__()
+            open_spans.append(ann)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(on_gc)
+
+    def uninstall() -> None:
+        gc.callbacks.remove(on_gc)
+        while open_spans:
+            open_spans.pop().__exit__(None, None, None)
+    return uninstall

@@ -65,6 +65,7 @@ def _global_norm(tree: Any) -> jax.Array:
                         for x in jax.tree_util.tree_leaves(tree)))
 
 
+@jax.named_scope("adamw")
 def adamw_update(params: Any, grads: Any, state: Dict[str, Any],
                  cfg: AdamWConfig) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
     step = state["step"] + 1

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from ..core.propagation import Replica, ShippingPolicy
 from ..core.tensor_lattice import DotSumStore, IntervalSum
+from ..obs.trace import span
 from .compression import TopKCompressor
 
 
@@ -89,17 +90,16 @@ class DeltaSyncPod(Replica):
 
     # -- current view -----------------------------------------------------------
     def params(self) -> Any:
-        decompress = (TopKCompressor.decompress
-                      if self.compressor is not None else None)
-        if self.compressor is not None:
-            # dots carry sparse updates: decompress each then sum
-            total = None
-            for _, upd in self.X.dots:
-                dense = TopKCompressor.decompress(upd)
-                total = dense if total is None else jax.tree_util.tree_map(
-                    lambda a, b: a + b, total, dense)
-            return self.outer.materialize_sum(total)
-        return self.outer.materialize(self.X)
+        with span("sync.params"):
+            if self.compressor is not None:
+                # dots carry sparse updates: decompress each then sum
+                total = None
+                for _, upd in self.X.dots:
+                    dense = TopKCompressor.decompress(upd)
+                    total = dense if total is None else jax.tree_util.tree_map(
+                        lambda a, b: a + b, total, dense)
+                return self.outer.materialize_sum(total)
+            return self.outer.materialize(self.X)
 
     # -- one training round ------------------------------------------------------
     def do_round(self) -> None:

@@ -36,6 +36,8 @@ import struct
 import zlib
 from typing import Any, Optional, Tuple
 
+from ..obs.trace import span
+
 MAGIC = b"\xd4W"
 # v2: store bodies carry a key-lifecycle table (epoch, expiry per key —
 # repro.lifecycle) and a per-group column-compression flag; digest bodies
@@ -272,51 +274,52 @@ class WireCodec:
         from .codec import (encode_digest, encode_store, encode_value,
                             store_body_is_empty)
 
-        mkind = msg[0]
-        if mkind == "ack":
-            return encode_frame("ack", _ACK.pack(int(msg[1])))
-        if mkind in ("reap", "reap-ack"):
-            key, epoch, expiry = msg[1], msg[2], msg[3]
-            ok = int(msg[4]) if mkind == "reap-ack" else 0
-            return encode_frame(mkind, _REAP.pack(int(epoch), float(expiry),
-                                                  ok)
-                                + key.encode("utf-8"))
-        if mkind == "handoff":
-            return encode_frame("handoff",
-                                encode_value(msg[1], self.compress))
-        if mkind == "digest":
-            return encode_frame("digest", encode_digest(msg[1]))
-        if mkind == "digest-resp":
-            # (store, requester digest): the known-versions/known-opaque/
-            # known-life filter runs AT ENCODE TIME — the response frame
-            # is built straight from resident state and carries only the
-            # rows the requester's digest provably lacks. When nothing
-            # survives the filter there is no frame at all (None: the
-            # engine's _post drops it), so a convergent mesh trades only
-            # digests — and the emptiness check costs nothing beyond the
-            # one encode pass that had to happen anyway.
-            _, store, digest = msg
-            body = encode_store(store, known_versions=digest.tensors,
-                                known_opaque=digest.opaque,
-                                known_life=digest.life,
-                                known_causal=digest.causal,
-                                compress=self.compress)
-            if store_body_is_empty(body):
-                return None
-            return encode_frame("digest-resp", body)
-        if mkind != "delta":  # pragma: no cover - engine ships no others
-            raise FrameError(f"unframeable message kind {mkind!r}")
-        if len(msg) == 2:                      # basic-mode delta-group
-            payload = encode_value(msg[1], self.compress)
-            body = _DELTA_BASIC.pack(0, len(payload)) + payload
-        else:                                  # causal delta-interval
-            _, d, n, ghost = msg
-            payload = encode_value(d, self.compress)
-            body = (_DELTA_CAUSAL.pack(1, int(n), int(ghost is not None),
-                                       len(payload)) + payload)
-            if ghost is not None:
-                body += encode_value(ghost, self.compress)
-        return encode_frame(self._payload_kind(msg[1], full_state), body)
+        with span("wire.encode"):
+            mkind = msg[0]
+            if mkind == "ack":
+                return encode_frame("ack", _ACK.pack(int(msg[1])))
+            if mkind in ("reap", "reap-ack"):
+                key, epoch, expiry = msg[1], msg[2], msg[3]
+                ok = int(msg[4]) if mkind == "reap-ack" else 0
+                return encode_frame(mkind, _REAP.pack(int(epoch),
+                                                      float(expiry), ok)
+                                    + key.encode("utf-8"))
+            if mkind == "handoff":
+                return encode_frame("handoff",
+                                    encode_value(msg[1], self.compress))
+            if mkind == "digest":
+                return encode_frame("digest", encode_digest(msg[1]))
+            if mkind == "digest-resp":
+                # (store, requester digest): the known-versions/known-opaque/
+                # known-life filter runs AT ENCODE TIME — the response frame
+                # is built straight from resident state and carries only the
+                # rows the requester's digest provably lacks. When nothing
+                # survives the filter there is no frame at all (None: the
+                # engine's _post drops it), so a convergent mesh trades only
+                # digests — and the emptiness check costs nothing beyond the
+                # one encode pass that had to happen anyway.
+                _, store, digest = msg
+                body = encode_store(store, known_versions=digest.tensors,
+                                    known_opaque=digest.opaque,
+                                    known_life=digest.life,
+                                    known_causal=digest.causal,
+                                    compress=self.compress)
+                if store_body_is_empty(body):
+                    return None
+                return encode_frame("digest-resp", body)
+            if mkind != "delta":  # pragma: no cover - engine ships no others
+                raise FrameError(f"unframeable message kind {mkind!r}")
+            if len(msg) == 2:                      # basic-mode delta-group
+                payload = encode_value(msg[1], self.compress)
+                body = _DELTA_BASIC.pack(0, len(payload)) + payload
+            else:                                  # causal delta-interval
+                _, d, n, ghost = msg
+                payload = encode_value(d, self.compress)
+                body = (_DELTA_CAUSAL.pack(1, int(n), int(ghost is not None),
+                                           len(payload)) + payload)
+                if ghost is not None:
+                    body += encode_value(ghost, self.compress)
+            return encode_frame(self._payload_kind(msg[1], full_state), body)
 
     @staticmethod
     def _payload_kind(value: Any, full_state: bool) -> str:
@@ -328,33 +331,34 @@ class WireCodec:
     def decode_msg(self, frame) -> Tuple:
         from .codec import decode_digest, decode_store, decode_value
 
-        dev = self.to_device
-        kind, payload = decode_frame(frame)
-        if kind == "ack":
-            return ("ack", _ACK.unpack_from(payload, 0)[0])
-        if kind in ("reap", "reap-ack"):
-            epoch, expiry, ok = _REAP.unpack_from(payload, 0)
-            key = bytes(payload[_REAP.size:]).decode("utf-8")
-            if kind == "reap":
-                return ("reap", key, int(epoch), float(expiry))
-            return ("reap-ack", key, int(epoch), float(expiry), int(ok))
-        if kind == "handoff":
-            return ("handoff", decode_value(payload, to_device=dev))
-        if kind == "digest":
-            return ("digest", decode_digest(payload))
-        if kind == "digest-resp":
-            return ("digest-resp", decode_store(payload, to_device=dev))
-        if kind in ("delta", "state", "membership"):
-            mode = payload[0]
-            if mode == 0:
-                _, plen = _DELTA_BASIC.unpack_from(payload, 0)
-                off = _DELTA_BASIC.size
-                return ("delta", decode_value(payload[off:off + plen],
-                                              to_device=dev))
-            _, n, has_ghost, plen = _DELTA_CAUSAL.unpack_from(payload, 0)
-            off = _DELTA_CAUSAL.size
-            d = decode_value(payload[off:off + plen], to_device=dev)
-            ghost = (decode_value(payload[off + plen:]) if has_ghost
-                     else None)
-            return ("delta", d, n, ghost)
-        raise FrameError(f"engine cannot route frame kind {kind!r}")
+        with span("wire.decode"):
+            dev = self.to_device
+            kind, payload = decode_frame(frame)
+            if kind == "ack":
+                return ("ack", _ACK.unpack_from(payload, 0)[0])
+            if kind in ("reap", "reap-ack"):
+                epoch, expiry, ok = _REAP.unpack_from(payload, 0)
+                key = bytes(payload[_REAP.size:]).decode("utf-8")
+                if kind == "reap":
+                    return ("reap", key, int(epoch), float(expiry))
+                return ("reap-ack", key, int(epoch), float(expiry), int(ok))
+            if kind == "handoff":
+                return ("handoff", decode_value(payload, to_device=dev))
+            if kind == "digest":
+                return ("digest", decode_digest(payload))
+            if kind == "digest-resp":
+                return ("digest-resp", decode_store(payload, to_device=dev))
+            if kind in ("delta", "state", "membership"):
+                mode = payload[0]
+                if mode == 0:
+                    _, plen = _DELTA_BASIC.unpack_from(payload, 0)
+                    off = _DELTA_BASIC.size
+                    return ("delta", decode_value(payload[off:off + plen],
+                                                  to_device=dev))
+                _, n, has_ghost, plen = _DELTA_CAUSAL.unpack_from(payload, 0)
+                off = _DELTA_CAUSAL.size
+                d = decode_value(payload[off:off + plen], to_device=dev)
+                ghost = (decode_value(payload[off + plen:]) if has_ghost
+                         else None)
+                return ("delta", d, n, ghost)
+            raise FrameError(f"engine cannot route frame kind {kind!r}")

@@ -14,8 +14,12 @@ The load-bearing properties:
   changing; collectors run at scrape time;
 * ``ReplicaProbes`` / ``AckLagProbe`` read engine health straight off a
   live replica (buffer depth, GC horizon age, write→acked latency);
-* kernel launches are observable by name through the process-wide hook;
-  ``KernelCounters`` is snapshot-and-diff only (no global reset);
+* ``KernelCounters`` is snapshot-and-diff only (no global reset);
+* program spans land on a ``jax.profiler`` trace's host plane, nested
+  as the calls nest (a resident join around its plan, dispatch and
+  rebuild; a receive around its decode and join); ``trace_gc`` marks a
+  collection and uninstalls cleanly; the store kernels and the train
+  step carry ``jax.named_scope`` names;
 * the scrape sidecar serves both views over real sockets;
 * the synthetic-trace anomaly detectors fire on exactly the corrupted
   streams they claim to catch;
@@ -23,9 +27,15 @@ The load-bearing properties:
 """
 
 import asyncio
+import contextlib
+import gc
+import glob
 import json
+import os
 import random
+import re
 
+import numpy as np
 import pytest
 
 from repro.core import (AWORSet, MVRegister, NetConfig, Replica, Simulator,
@@ -35,7 +45,7 @@ from repro.obs import (AckLagProbe, EVENT_KINDS, MetricsServer, Registry,
                        ReplicaProbes, Tracer, anomalies, convergence,
                        load_trace, marker_lag_histogram, merge_events,
                        parse_prometheus, redundancy, report, scrape,
-                       scrape_json, semantic_trace, trace_kernel_launches)
+                       scrape_json, semantic_trace, span, trace_gc)
 
 
 # ---------------------------------------------------------------------------
@@ -360,22 +370,206 @@ def test_kernel_counters_snapshot_and_diff_only():
     assert diff["launches"] == 1 and diff["h2d_bytes"] == 0
 
 
-def test_kernel_launch_hook_names_ops(monkeypatch):
-    import numpy as np
-    from repro.kernels import ops
+# ---------------------------------------------------------------------------
+# Program spans on the profiler's clock
+# ---------------------------------------------------------------------------
 
-    tr = Tracer(node="kern")
-    uninstall = trace_kernel_launches(tr)
+@contextlib.contextmanager
+def _profiled(log_dir):
+    """Profile the block on the CPU; afterwards the yielded list holds
+    every ``repro.*`` host span of the trace as ``(name, start_ns,
+    end_ns)``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    spans = []
+    jax.profiler.start_trace(str(log_dir))
     try:
-        x = np.zeros((2, 256), np.float32)
-        ops.chunk_digest_auto(x)
+        yield spans
     finally:
-        uninstall()
-    evs = [e for e in tr.events() if e["kind"] == "kernel_launch"]
-    assert evs and evs[-1]["op"] == "chunk_digest"
-    assert evs[-1]["h2d_bytes"] == x.nbytes
-    ops.record_launch("after_uninstall")      # hook removed: no emit
-    assert len(tr.events()) == len(evs)
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans.extend((ev.name, ev.start_ns,
+                              ev.start_ns + ev.duration_ns)
+                             for ev in line.events
+                             if ev.name.startswith("repro."))
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == "repro." + name]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def _resident_pair(wire=False):
+    """Two causal resident store replicas recovered from one bulk store
+    of 4 keys x 4 chunks of 32, and their simulator."""
+    from repro.core.tensor_lattice import ChunkedTensor, TensorState
+    from repro.core.store import LatticeStore
+    from repro.kernels import resident
+    from repro.wire import WireCodec
+
+    rng = np.random.default_rng(0)
+    base = LatticeStore.of({f"k{k}": TensorState.of({"w": ChunkedTensor(
+        rng.normal(size=(4, 32)).astype(np.float32),
+        np.ones((4,), np.int32))}, lamport=1) for k in range(4)})
+    sim = Simulator(NetConfig(seed=0))
+    codec = WireCodec(to_device=True) if wire else None
+    reps = [sim.add_node(StoreReplica(i, [j for j in ("a", "b") if j != i],
+                                      causal=True, wire=codec,
+                                      resident=True))
+            for i in ("a", "b")]
+    for r in reps:
+        r.recover((base, 0))
+    resident.ensure(base)
+    return sim, reps
+
+
+def _row_delta(version, chunks=4, chunk=32, row=1):
+    from repro.core.tensor_lattice import TensorState, sparse_chunks
+    return TensorState.of({"w": sparse_chunks(
+        chunks, np.array([row]), np.full((1, chunk), 0.5, np.float32),
+        np.array([version], np.int32))}, lamport=version)
+
+
+def test_span_is_the_profilers_annotation():
+    import jax
+    assert isinstance(jax.profiler.TraceAnnotation("x"), type(span("x")))
+    with span("anything"):       # a no-op with no profiler running
+        pass
+
+
+def test_resident_put_records_join_around_its_children(tmp_path):
+    sim, (a, _) = _resident_pair()
+    with _profiled(tmp_path) as spans:
+        a.put("k2", _row_delta(7))
+    (join,) = _named(spans, "resident.join")
+    for child in ("resident.plan", "resident.dispatch", "resident.rebuild"):
+        (c,) = _named(spans, child)
+        assert _inside(c, join), child
+    assert not _named(spans, "python.gc")     # trace_gc not installed
+
+
+def test_host_store_join_records_no_resident_span(tmp_path):
+    from repro.core.store import LatticeStore
+    from repro.core.tensor_lattice import ChunkedTensor, TensorState
+    x = LatticeStore.of({"k": TensorState.of({"w": ChunkedTensor(
+        np.ones((2, 8), np.float32), np.ones((2,), np.int32))})})
+    with _profiled(tmp_path) as spans:
+        x.join(LatticeStore.key_delta("k", _row_delta(3, 2, 8, 0)))
+    assert not _named(spans, "resident.join")
+
+
+def test_gossip_tick_nests_codec_and_joins_in_engine_spans(tmp_path):
+    sim, (a, b) = _resident_pair(wire=True)
+    a.put("k0", _row_delta(5))
+    with _profiled(tmp_path) as spans:
+        for r in (a, b):
+            r.on_periodic()
+            r.gc_deltas()
+        sim.run_for(2.0)
+        assert converged([a, b])
+        b.get("k0")
+    periodic = _named(spans, "engine.periodic")
+    receive = _named(spans, "engine.receive")
+    assert len(periodic) == 2 and receive
+    assert len(_named(spans, "engine.gc_deltas")) == 2
+    encodes, decodes = _named(spans, "wire.encode"), _named(spans,
+                                                            "wire.decode")
+    # deltas leave in a periodic broadcast, acks in the receive of one
+    assert any(_inside(e, p) for e in encodes for p in periodic)
+    assert all(any(_inside(e, p) for p in periodic + receive)
+               for e in encodes)
+    assert len(decodes) == len(receive)
+    assert all(any(_inside(d, r) for r in receive) for d in decodes)
+    # b's join of a's delta happens inside b's receive of it
+    joins = _named(spans, "resident.join")
+    assert joins and all(any(_inside(j, r) for r in receive)
+                         for j in joins)
+    assert len(_named(spans, "antientropy.converged")) == 1
+    assert len(_named(spans, "store.get")) == 1
+
+
+def test_delta_sync_params_is_a_span(tmp_path):
+    from repro.sync import DeltaSyncPod
+    pod = DeltaSyncPod("pod0", ["pod1"], {"w": np.zeros(3, np.float32)},
+                       lambda p, r, i: p, num_pods=2)
+    with _profiled(tmp_path) as spans:
+        pod.params()
+    assert len(_named(spans, "sync.params")) == 1
+
+
+def test_trace_gc_marks_a_collection_and_uninstalls(tmp_path):
+    before = list(gc.callbacks)
+    with _profiled(tmp_path) as spans:
+        uninstall = trace_gc()
+        try:
+            gc.collect()
+        finally:
+            uninstall()
+        gc.collect()                       # after: not marked
+    assert gc.callbacks == before
+    # one full collection, marked once
+    assert len(_named(spans, "python.gc")) == 1
+
+
+def test_kernel_launch_bridge_is_gone():
+    from repro.kernels import ops
+    assert "kernel_launch" not in EVENT_KINDS
+    assert not hasattr(ops, "set_launch_hook")
+    with pytest.raises(ValueError, match="unknown trace event kind"):
+        Tracer().emit("kernel_launch", op="x")
+
+
+@pytest.mark.parametrize("jitted,args,module", [
+    ("_scatter_join_ref_jit", "scatter", "jit_scatter_join_ref"),
+    ("_fused_join_digest_ref_jit", "fused", "jit_fused_join_digest_ref"),
+    ("_chunk_digest_ref_jit", "digest", "jit_chunk_digest_ref"),
+])
+def test_store_kernels_carry_named_scopes(jitted, args, module):
+    import jax.numpy as jnp
+    from repro.kernels import ops
+    rows, w = 8, 16
+    vals, vers = jnp.zeros((rows, w)), jnp.zeros((rows,), jnp.int32)
+    col = jnp.zeros((rows,))
+    operands = {
+        "scatter": (vals, vers, col, col, jnp.arange(2, dtype=jnp.int32),
+                    jnp.ones((2, w)), jnp.ones((2,), jnp.int32)),
+        "fused": (vals, vers, vals, vers),
+        "digest": (vals,),
+    }[args]
+    text = getattr(ops, jitted).lower(*operands).as_text(debug_info=True)
+    assert f"module @{module} " in text          # the module keeps its name
+    assert f"store.{module[len('jit_'):]}" in text
+
+
+def test_train_step_ops_are_named_by_part():
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.models import init_model
+    from repro.optim.adamw import init_opt_state
+    from repro.runtime.steps import make_train_step
+
+    cfg = get_config("qwen1.5-0.5b", reduced=True)
+    params = jax.eval_shape(
+        lambda: init_model(cfg, jax.random.PRNGKey(0))[0])
+    opt = jax.eval_shape(init_opt_state, params)
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    text = jax.jit(make_train_step(cfg)).lower(
+        params, opt, {"tokens": tok, "labels": tok}).as_text(
+            debug_info=True)
+    # a scope reads ``jit(train_step)/jvp(layers)/...`` on the forward
+    # pass and ``transpose(jvp(layers))`` on the backward
+    for part in ("embed", "layers", "attention", "mlp", "head", "loss",
+                 "adamw"):
+        assert re.search(rf"[/(]{part}[)/]", text), part
 
 
 # ---------------------------------------------------------------------------

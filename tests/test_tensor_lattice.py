@@ -20,7 +20,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core.tensor_lattice import (ChunkedTensor, DotSumStore,
                                        IntervalSum, TensorState, chunk_tensor,
                                        pack_delta, packed_size_bytes,
-                                       unchunk, unpack_delta)
+                                       sparse_chunks, unchunk, unpack_delta)
 
 NAMES = ["w1", "w2"]
 N_CHUNKS = 4
@@ -120,6 +120,33 @@ def test_version_tie_break_is_deterministic():
     assert ab == ba
     got = np.asarray(unchunk(ab.as_dict()["w1"], (N_CHUNKS, CHUNK)))[0]
     assert np.allclose(got, 9)  # same lamport, rank 1 > rank 0
+
+
+def _chunks(n_chunks, width, dtype, sparse):
+    vals = np.ones((n_chunks, width), dtype)
+    vers = np.full((n_chunks,), 3, np.int32)
+    if sparse:
+        return sparse_chunks(n_chunks, np.arange(n_chunks), vals, vers)
+    return ChunkedTensor(vals, vers)
+
+
+@pytest.mark.parametrize("other", [(2, 8, np.float32), (1, 4, np.float32),
+                                   (1, 8, np.int32)],
+                         ids=["chunk-count", "width", "dtype"])
+@pytest.mark.parametrize("a_sparse,b_sparse", [(False, False),
+                                               (False, True),
+                                               (True, True)],
+                         ids=["dense-dense", "dense-sparse", "sparse-sparse"])
+def test_join_of_mismatched_chunk_layouts_raises(other, a_sparse, b_sparse):
+    """One tensor name holds one ``[n_chunks, chunk]`` layout and dtype;
+    joining two others is a type error in every density mix, in either
+    order, never a broadcast or an index past the end."""
+    a = TensorState.of({"w": _chunks(1, 8, np.float32, a_sparse)})
+    b = TensorState.of({"w": _chunks(*other, b_sparse)})
+    with pytest.raises(ValueError, match="cannot join chunk tensors"):
+        a.join(b)
+    with pytest.raises(ValueError, match="cannot join chunk tensors"):
+        b.join(a)
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ import pytest
 import pytest as _pytest
 _pytest.importorskip(
     "hypothesis", reason="dev dependency — pip install -r requirements-dev.txt")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 import numpy as np
 
@@ -84,16 +84,38 @@ def test_decode_encode_is_identity(store):
     assert dec.leq(store) and store.leq(dec)
 
 
+def _one_tensor(n_chunks, width, dtype=np.float32, version=1,
+                sparse=False, extra=None):
+    """A one-key store whose tensor ``t0`` holds ``n_chunks`` rows of
+    ``width`` at ``version``; ``extra`` adds keys beside it."""
+    vals = np.arange(n_chunks * width).reshape(n_chunks, width).astype(dtype)
+    vers = np.full((n_chunks,), version, np.int32)
+    ct = (sparse_chunks(n_chunks, np.arange(n_chunks, dtype=np.int32), vals,
+                        vers) if sparse else ChunkedTensor(vals, vers))
+    return LatticeStore.of({"key0": TensorState.of({"t0": ct}),
+                            **(extra or {})})
+
+
 @settings(max_examples=25, deadline=None)
 @given(resident=stores(), delta=stores())
+# the same tensor name at two layouts or dtypes: once the dense join
+# broadcast while the decoded (sparse) delta's join raised or differed
+@example(resident=_one_tensor(1, 4, sparse=True),
+         delta=_one_tensor(1, 8, version=3))
+@example(resident=_one_tensor(2, 4, version=3,
+                              extra={"key1": GCounter.bottom()}),
+         delta=_one_tensor(1, 4, np.int32, version=3))
+@example(resident=_one_tensor(1, 4, version=0),
+         delta=_one_tensor(2, 4, version=2))
 def test_decoded_store_joins_identically(resident, delta):
     dec = decode_store(encode_store(delta))
     try:
         want = resident.join(delta)
     except Exception:
         # key-type mismatch between the two random stores (joining a
-        # counter into a tensor key is a type error with or without the
-        # codec) — not a wire property
+        # counter into a tensor key, or a tensor into one of another
+        # layout, is a type error with or without the codec) — not a
+        # wire property
         return
     assert resident.join(dec) == want
 
