@@ -30,12 +30,15 @@ anti-entropy nodes in ``repro.core.antientropy`` run unchanged over them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs.registry import global_registry
 
 # version = (lamport << RANK_BITS) | writer_rank, stored in a jnp integer
 # array. Without jax_enable_x64 jnp canonicalizes int64 → int32, so keep the
@@ -646,13 +649,73 @@ def packed_size_bytes(wire: Dict[str, Any]) -> int:
 # Additive dot-store (pseudo-gradient aggregation) + §7.2-style compression
 # ---------------------------------------------------------------------------
 
-def _tree_equal(a, b) -> bool:
-    la, ta = jax.tree_util.tree_flatten(a)
-    lb, tb = jax.tree_util.tree_flatten(b)
-    if ta != tb or len(la) != len(lb):
+def _count_eq_bytes(where: str, pairs) -> None:
+    """Both sides' bytes of the array leaves compared ``where``."""
+    global_registry().counter(
+        "repro_dotstore_eq_bytes_total", "payload bytes DotSumStore.__eq__ compared, by where",
+        ("where",)).labels(where).inc(
+            sum(getattr(x, "nbytes", 0) for pair in pairs for x in pair))
+
+
+@functools.lru_cache(maxsize=None)
+def _device_holds(dtype) -> bool:
+    """Whether a device array can have ``dtype`` exactly: no float64 or
+    int64 without x64, no strings, objects or foreign byte order."""
+    try:
+        return (jax.dtypes.canonicalize_dtype(dtype) == dtype
+                and jnp.result_type(dtype) == dtype)
+    except TypeError:
         return False
-    return all(np.array_equal(np.asarray(x), np.asarray(y))
-               for x, y in zip(la, lb))
+
+
+def _on_device(x, y) -> bool:
+    """Whether ``np.array_equal(x, y)`` can be computed on a device: one
+    side is a ``jax.Array``, the other has a dtype, and both dtypes and the
+    one numpy promotes the pair to are held exactly there."""
+    if not (isinstance(x, jax.Array) or isinstance(y, jax.Array)):
+        return False
+    try:
+        dts = (x.dtype, y.dtype, np.result_type(x.dtype, y.dtype))
+    except (AttributeError, TypeError):
+        return False
+    return all(_device_holds(d) for d in dts)
+
+
+@jax.jit
+def _all_array_equal(xs, ys):
+    """One device bool: every pair equal elementwise in the dtype numpy
+    promotes it to (so NaN is unequal to itself). Compiled once per
+    structure, shapes and dtypes of the pairs."""
+    ok = jnp.bool_(True)
+    for x, y in zip(xs, ys):
+        dt = np.result_type(x.dtype, y.dtype)
+        ok = ok & jnp.all(x.astype(dt) == y.astype(dt))
+    return ok
+
+
+def _payloads_equal(pairs) -> bool:
+    """``all(np.array_equal(x, y) for x, y in pairs)``, with every pair
+    that involves a ``jax.Array`` compared on its device and the answer
+    read back as one bool (one ``jax.device_get``). Pairs of numpy leaves,
+    and pairs whose dtypes the device cannot hold, stay on the host.
+    The payloads of one call share a set of devices."""
+    host, dev = [], []
+    for x, y in pairs:
+        if _on_device(x, y):
+            if x.shape != y.shape:
+                return False
+            dev.append((x, y))
+        else:
+            host.append((x, y))
+    for x, y in host:
+        _count_eq_bytes("host", [(x, y)])
+        if not np.array_equal(x, y):
+            return False
+    if not dev:
+        return True
+    _count_eq_bytes("device", dev)
+    xs, ys = zip(*dev)
+    return bool(jax.device_get(_all_array_equal(list(xs), list(ys))))
 
 
 @dataclass(frozen=True, eq=False)
@@ -705,7 +768,16 @@ class DotSumStore:
         if not isinstance(other, DotSumStore):
             return NotImplemented
         a, b = self.as_dict(), other.as_dict()
-        return set(a) == set(b) and all(_tree_equal(a[k], b[k]) for k in a)
+        if set(a) != set(b):
+            return False
+        pairs = []
+        for k in a:
+            la, ta = jax.tree_util.tree_flatten(a[k])
+            lb, tb = jax.tree_util.tree_flatten(b[k])
+            if ta != tb or len(la) != len(lb):
+                return False
+            pairs.extend(zip(la, lb))
+        return _payloads_equal(pairs)
 
     def __hash__(self):  # pragma: no cover
         raise TypeError("unhashable")

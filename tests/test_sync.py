@@ -64,6 +64,43 @@ def test_delta_sync_pods_converge_over_lossy_network():
         assert not n.ghost_failures
 
 
+def test_converged_pods_compare_payloads_on_device(monkeypatch):
+    """Once two pods hold the same dots, the convergence check compares
+    every payload on the device and reads back one bool, nothing else."""
+    from repro.obs import global_registry
+
+    def eq_bytes():
+        got = global_registry().snapshot().get(
+            "repro_dotstore_eq_bytes_total") or {}
+        return {w: got.get(w, 0.0) for w in ("device", "host")}
+
+    sim, pods = _mk_pods(2, loss=0.2, seed=5)
+    for _ in range(2):
+        for p in pods:
+            p.do_round()
+        sim.run_for(3.0)
+    run_to_convergence(sim, pods, interval=1.0, max_time=20_000)
+    assert len(pods[0].X.dots) == 2 * 2
+    reads = []
+    device_get = jax.device_get
+
+    def spy(x):
+        reads.append(x)
+        return device_get(x)
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    before = eq_bytes()
+    assert converged(pods)
+    after = eq_bytes()
+    assert len(reads) == 1
+    assert isinstance(reads[0], jax.Array)
+    assert reads[0].shape == () and reads[0].dtype == jnp.bool_
+    assert after["host"] == before["host"]
+    compared = sum(leaf.nbytes for p in pods for _, upd in p.X.dots
+                   for leaf in jax.tree_util.tree_leaves(upd))
+    assert after["device"] - before["device"] == compared
+
+
 def test_delta_sync_with_topk_compression_converges():
     sim, pods = _mk_pods(3, loss=0.2, seed=7, compressor_rate=0.5,
                          ghost=False)

@@ -171,6 +171,84 @@ def test_dotsum_duplicate_and_reorder_safe():
     assert np.allclose(np.asarray(X.total()["a"]), np.asarray(want))
 
 
+def _one_dot(payload, dot=("p0", 1)):
+    return DotSumStore(((dot, payload),))
+
+
+def _eq_bytes():
+    from repro.obs import global_registry
+    got = global_registry().snapshot().get("repro_dotstore_eq_bytes_total")
+    return {w: (got or {}).get(w, 0.0) for w in ("device", "host")}
+
+
+def _array_equal_eq(a, b):
+    """What equality was before it moved to the device: every leaf pair
+    compared with ``np.array_equal`` on the host."""
+    da, db = a.as_dict(), b.as_dict()
+    if set(da) != set(db):
+        return False
+    for k in da:
+        la, ta = jax.tree_util.tree_flatten(da[k])
+        lb, tb = jax.tree_util.tree_flatten(db[k])
+        if ta != tb or not all(np.array_equal(np.asarray(x), np.asarray(y))
+                               for x, y in zip(la, lb)):
+            return False
+    return True
+
+
+def _bf16(values):
+    return jnp.asarray(np.asarray(values, np.float32)).astype(jnp.bfloat16)
+
+
+_NAN = _one_dot({"w": jnp.asarray([1.0, np.nan], jnp.float32)})
+
+
+@pytest.mark.parametrize("a,b,equal,where", [
+    pytest.param(_one_dot({"w": _bf16([1, 2, 3])}),
+                 _one_dot({"w": _bf16([1, 2, 3]) + 0}), True, "device",
+                 id="bf16-distinct-buffers"),
+    pytest.param(_one_dot({"w": _bf16([1, 2, 3])}),
+                 _one_dot({"w": _bf16([1, 2, 4])}), False, "device",
+                 id="one-element-differs"),
+    pytest.param(_NAN, _NAN, False, "device", id="nan-same-object"),
+    pytest.param(_one_dot({"w": jnp.zeros((2, 3))}),
+                 _one_dot({"w": jnp.zeros((3, 2))}), False, None,
+                 id="shape-mismatch"),
+    pytest.param(_one_dot({"w": jnp.zeros(3)}),
+                 _one_dot({"v": jnp.zeros(3)}), False, None,
+                 id="treedef-mismatch"),
+    pytest.param(_one_dot({"w": np.arange(3.0), "b": np.float32(1)}),
+                 _one_dot({"w": np.arange(3.0), "b": np.float32(1)}), True,
+                 "host", id="numpy-both-sides"),
+    pytest.param(_one_dot({"w": np.arange(3, dtype=np.float32)}),
+                 _one_dot({"w": jnp.arange(3, dtype=jnp.float32)}), True,
+                 "device", id="numpy-against-jax"),
+    pytest.param(_one_dot({"w": _bf16([1, 2])}),
+                 _one_dot({"w": jnp.asarray([1, 2], jnp.float32)}), True,
+                 "device", id="bf16-against-f32-promotes"),
+    pytest.param(_one_dot({"w": _bf16([1, 2])}),
+                 _one_dot({"w": jnp.asarray([1, 2.001], jnp.float32)}),
+                 False, "device", id="promoted-values-differ"),
+    pytest.param(_one_dot({"w": jnp.asarray([16777217], jnp.int32)}),
+                 _one_dot({"w": np.asarray([16777216], np.float32)}), False,
+                 "host", id="promotion-past-the-device-stays-on-host"),
+    pytest.param(_one_dot({"w": jnp.zeros(3)}),
+                 _one_dot({"w": jnp.zeros(3)}, dot=("p1", 1)), False, None,
+                 id="different-dot-sets"),
+])
+def test_dotsum_eq_is_array_equal_on_every_leaf(a, b, equal, where):
+    """Equality keeps what ``np.array_equal`` decides on every leaf pair,
+    NaN unequal to itself included; pairs with a device array are compared
+    there, numpy pairs on the host, and an early exit compares nothing."""
+    before = _eq_bytes()
+    assert (a == b) is equal
+    assert _array_equal_eq(a, b) is equal
+    moved = {w: _eq_bytes()[w] - before[w] for w in before}
+    compared = sum(getattr(x, "nbytes", 0) for s in (a, b) for _, p in s.dots
+                   for x in jax.tree_util.tree_leaves(p))
+    assert moved == {w: (compared if w == where else 0.0) for w in moved}
+
+
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 2**31 - 1))
 def test_dotsum_lattice_laws(seed):
