@@ -23,6 +23,7 @@ _MODULES: Dict[str, str] = {
     "mamba2-130m": "mamba2_130m",
     "musicgen-large": "musicgen_large",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "kanana-2-30b-a3b": "kanana_2_30b_a3b",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

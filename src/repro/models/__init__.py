@@ -3,10 +3,10 @@
 from .config import (LayerSpec, MLASpec, ModelConfig, MoESpec, SSMSpec,
                      layout_groups)
 from .transformer import (decode_step, forward, init_caches, init_model,
-                          prefill, train_loss)
+                          prefill, train_loss, train_loss_and_stats)
 
 __all__ = [
     "LayerSpec", "MLASpec", "ModelConfig", "MoESpec", "SSMSpec",
     "layout_groups", "decode_step", "forward", "init_caches", "init_model",
-    "prefill", "train_loss",
+    "prefill", "train_loss", "train_loss_and_stats",
 ]

@@ -17,6 +17,11 @@ from typing import List, Optional, Sequence, Tuple
 
 @dataclass(frozen=True)
 class MoESpec:
+    """Routed experts as the published gate defines them. ``num_experts``
+    is the router's width; ``held`` says which of them this layer holds,
+    ``(first, count)``: the layer computes only their part of the result,
+    with no capacity and no dropped token. ``held=None`` holds every
+    expert behind the capacity-bounded dispatch (``capacity_factor``)."""
     num_experts: int
     top_k: int
     expert_d_ff: int
@@ -24,6 +29,15 @@ class MoESpec:
     shared_d_ff: int = 0          # per shared expert
     router_noise: float = 0.0
     capacity_factor: float = 1.25
+    score_func: str = "softmax"   # "softmax" | "sigmoid"
+    norm_topk: bool = True        # top-k weights renormalised to sum 1
+    routed_scale: float = 1.0     # routed_scaling_factor
+    select_bias: bool = False     # init ``bias``, per expert, for selection
+    router_f32: bool = False      # router logits at full f32 precision
+    held: Optional[Tuple[int, int]] = None
+
+    def held_count(self) -> int:
+        return self.num_experts if self.held is None else self.held[1]
 
 
 @dataclass(frozen=True)
@@ -38,8 +52,9 @@ class SSMSpec:
 
 @dataclass(frozen=True)
 class MLASpec:
-    """DeepSeek-V2 multi-head latent attention dims."""
-    q_lora_rank: int = 1536
+    """DeepSeek-V2 multi-head latent attention dims; ``q_lora_rank=None``
+    projects queries directly, with no query latent."""
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -130,9 +145,10 @@ class ModelConfig:
             elif spec.kind == "mla":
                 m = self.mla
                 qh = m.qk_nope_head_dim + m.qk_rope_head_dim
-                t = a = (d * m.q_lora_rank
-                         + m.q_lora_rank * self.n_heads * qh
-                         + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                q = (d * self.n_heads * qh if m.q_lora_rank is None
+                     else d * m.q_lora_rank
+                     + m.q_lora_rank * self.n_heads * qh)
+                t = a = (q + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                          + m.kv_lora_rank * self.n_heads
                          * (m.qk_nope_head_dim + m.v_head_dim)
                          + self.n_heads * m.v_head_dim * d)
@@ -156,8 +172,9 @@ class ModelConfig:
                 gates = 2 if self.act in ("swiglu", "geglu") else 1
                 per_expert = (gates + 1) * d * m.expert_d_ff
                 shared = m.num_shared_experts * (gates + 1) * d * m.shared_d_ff
-                router = d * m.num_experts
-                t += m.num_experts * per_expert + shared + router
+                router = d * m.num_experts + (m.num_experts
+                                              if m.select_bias else 0)
+                t += m.held_count() * per_expert + shared + router
                 a += m.top_k * per_expert + shared + router
             total += t
             active += a

@@ -1,6 +1,7 @@
-"""Multi-head Latent Attention (DeepSeek-V2).
+"""Multi-head Latent Attention (DeepSeek-V2/V3).
 
-Queries and keys/values are factored through low-rank latents; the decode
+Keys/values are factored through a low-rank latent, and queries through
+one too unless ``q_lora_rank`` is None (then one direct projection); the decode
 cache stores ONLY the compressed kv-latent (kv_lora=512) plus the shared
 rope key (64) per token — independent of the 128 heads — and decode runs
 with *weight absorption*: scores are computed directly in latent space
@@ -13,6 +14,7 @@ the dry-run.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -30,22 +32,27 @@ def init_mla(cfg, key, dtype) -> Tuple[Dict, Dict]:
     qh = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 6)
     sc = lambda n: float(1.0 / np.sqrt(n))
-    p = {
-        "w_dq": jax.random.normal(ks[0], (d, m.q_lora_rank), dtype) * sc(d),
-        "w_uq": jax.random.normal(ks[1], (m.q_lora_rank, H * qh), dtype) * sc(m.q_lora_rank),
+    if m.q_lora_rank is None:          # queries projected directly
+        p = {"w_q": jax.random.normal(ks[0], (d, H * qh), dtype) * sc(d)}
+        s = {"w_q": ("embed", "heads")}
+    else:
+        p = {"w_dq": jax.random.normal(ks[0], (d, m.q_lora_rank), dtype) * sc(d),
+             "w_uq": jax.random.normal(ks[1], (m.q_lora_rank, H * qh), dtype) * sc(m.q_lora_rank),
+             "q_norm": {"scale": jnp.ones((m.q_lora_rank,), jnp.float32)}}
+        s = {"w_dq": ("embed", "lora"), "w_uq": ("lora", "heads"),
+             "q_norm": {"scale": (None,)}}
+    p.update({
         "w_dkv": jax.random.normal(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype) * sc(d),
         "w_uk": jax.random.normal(ks[3], (m.kv_lora_rank, H * m.qk_nope_head_dim), dtype) * sc(m.kv_lora_rank),
         "w_uv": jax.random.normal(ks[4], (m.kv_lora_rank, H * m.v_head_dim), dtype) * sc(m.kv_lora_rank),
         "wo": jax.random.normal(ks[5], (H * m.v_head_dim, d), dtype) * sc(H * m.v_head_dim),
-        "q_norm": {"scale": jnp.ones((m.q_lora_rank,), jnp.float32)},
         "kv_norm": {"scale": jnp.ones((m.kv_lora_rank,), jnp.float32)},
-    }
-    s = {
-        "w_dq": ("embed", "lora"), "w_uq": ("lora", "heads"),
+    })
+    s.update({
         "w_dkv": ("embed", "lora"), "w_uk": ("lora", "heads"),
         "w_uv": ("lora", "heads"), "wo": ("heads", "embed"),
-        "q_norm": {"scale": (None,)}, "kv_norm": {"scale": (None,)},
-    }
+        "kv_norm": {"scale": (None,)},
+    })
     return p, s
 
 
@@ -54,9 +61,13 @@ def _queries(p, cfg, x, positions):
     b, s, _ = x.shape
     H = cfg.n_heads
     qh = m.qk_nope_head_dim + m.qk_rope_head_dim
-    cq = apply_norm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["w_dq"]),
-                    "rms")
-    q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"]).reshape(b, s, H, qh)
+    if "w_q" in p:
+        q = jnp.einsum("bsd,dh->bsh", x, p["w_q"])
+    else:
+        cq = apply_norm(p["q_norm"], jnp.einsum("bsd,dr->bsr", x, p["w_dq"]),
+                        "rms")
+        q = jnp.einsum("bsr,rh->bsh", cq, p["w_uq"])
+    q = q.reshape(b, s, H, qh)
     q_nope = q[..., :m.qk_nope_head_dim]
     q_rope = apply_rope(q[..., m.qk_nope_head_dim:], positions,
                         cfg.rope_theta)
@@ -72,14 +83,16 @@ def _latents(p, cfg, x, positions):
     return ckv, k_rope
 
 
-def _mla_attend_naive(cfg, q_nope, q_rope, k_nope, k_rope, v, positions):
+def _mla_attend_naive(cfg, q_nope, q_rope, k_nope, k_rope, v, positions,
+                      k_positions=None):
     m = cfg.mla
+    k_positions = positions if k_positions is None else k_positions
     scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     scores = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bshr,btr->bhst", q_rope, k_rope,
                            preferred_element_type=jnp.float32)) * scale
-    causal = positions[:, None, :] <= positions[:, :, None]
+    causal = k_positions[:, None, :] <= positions[:, :, None]
     scores = jnp.where(causal[:, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhst,bthv->bshv", probs, v)
@@ -87,54 +100,37 @@ def _mla_attend_naive(cfg, q_nope, q_rope, k_nope, k_rope, v, positions):
 
 def _mla_attend_chunked(cfg, q_nope, q_rope, k_nope, k_rope, v, positions,
                         block: int):
-    """Trace-time flash MLA: [bq × bk] tiles + online softmax, upper-
-    triangle tiles skipped statically (see attention._mha_chunked)."""
-    m = cfg.mla
-    b, s, H, nd = q_nope.shape
-    t = k_nope.shape[1]
-    vd = v.shape[-1]
+    """Query blocks of ``block`` rows, each against the keys up to its last
+    row, so no block above the diagonal is formed. Each block runs under
+    ``jax.checkpoint``: a backward pass holds one block's scores at a
+    time, not the whole [s, s] triangle. (Tiles of keys with an online
+    softmax held every tile's scores of a layer in its backward pass;
+    with a checkpoint per query block they still took 4.4x as long to
+    compile at 8,192 positions.)"""
+    s = q_nope.shape[1]
     bq = min(block, s)
-    bk = min(block, t)
-    if s % bq or t % bk:
+    if s % bq or k_nope.shape[1] != s:
         return _mla_attend_naive(cfg, q_nope, q_rope, k_nope, k_rope, v,
                                  positions)
-    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    out_blocks = []
+    one = jax.checkpoint(functools.partial(_mla_attend_naive, cfg))
+    out = []
     for iq in range(s // bq):
-        sl = slice(iq * bq, (iq + 1) * bq)
-        qn, qr, qp = q_nope[:, sl], q_rope[:, sl], positions[:, sl]
-        mstat = jnp.full((b, H, bq), NEG_INF, jnp.float32)
-        l = jnp.zeros((b, H, bq), jnp.float32)
-        acc = jnp.zeros((b, H, bq, vd), jnp.float32)
-        for ik in range(t // bk):
-            if ik * bk > (iq + 1) * bq - 1:
-                continue                      # above the diagonal
-            ksl = slice(ik * bk, (ik + 1) * bk)
-            sc = (jnp.einsum("bshn,bthn->bhst", qn, k_nope[:, ksl],
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bshr,btr->bhst", qr, k_rope[:, ksl],
-                               preferred_element_type=jnp.float32)) * scale
-            mask = (positions[:, ksl][:, None, :] <= qp[:, :, None])
-            mask = mask[:, None, :, :]                        # [b,1,bq,bk]
-            sc_masked = jnp.where(mask, sc, NEG_INF)
-            m_new = jnp.maximum(mstat, jnp.max(sc_masked, axis=-1))
-            alpha = jnp.exp(mstat - m_new)
-            pprob = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
-            l = l * alpha + jnp.sum(pprob, axis=-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bhst,bthv->bhsv", pprob.astype(v.dtype), v[:, ksl]
-            ).astype(jnp.float32)
-            mstat = m_new
-        safe_l = jnp.where(l > 0, l, 1.0)
-        ob = (acc / safe_l[..., None]).astype(q_nope.dtype)
-        out_blocks.append(ob.transpose(0, 2, 1, 3))           # [b,bq,H,vd]
-    return jnp.concatenate(out_blocks, axis=1)
+        sl, ks = slice(iq * bq, (iq + 1) * bq), slice(0, (iq + 1) * bq)
+        out.append(one(q_nope[:, sl], q_rope[:, sl], k_nope[:, ks],
+                       k_rope[:, ks], v[:, ks], positions[:, sl],
+                       positions[:, ks]))
+    return jnp.concatenate(out, axis=1)
 
 
 def mla_full(p: Dict, cfg, spec, x: jax.Array, positions: jax.Array,
              make_cache: Optional[int] = None
              ) -> Tuple[jax.Array, Optional[Dict]]:
     """Train/prefill: materialized keys/values (matmul-rich, MXU-friendly)."""
+    with jax.named_scope("mla"):
+        return _mla_full(p, cfg, x, positions, make_cache)
+
+
+def _mla_full(p, cfg, x, positions, make_cache):
     m = cfg.mla
     b, s, _ = x.shape
     H = cfg.n_heads

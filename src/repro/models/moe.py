@@ -25,7 +25,19 @@ in DESIGN.md). The router is replicated (it is d·E ≪ anything).
 
 Shared experts (deepseek) run densely outside the shard_map.
 
-The Switch-style load-balance auxiliary loss is returned by both paths.
+``held`` (a layer told which experts it holds, ``MoESpec.held``): the
+dropless path of one expert-parallel shard. The router scores all of the
+router's experts; the (token, slot) pairs routed to held experts are
+sorted by expert and the held experts' SwiGLU runs as grouped matmuls
+over exactly those rows (megablox ``gmm`` on the TPU, ``ragged_dot``
+elsewhere), with no capacity; pairs routed to experts held elsewhere add
+nothing here. That partial result is what this shard gives the layer.
+
+Routers score by softmax or sigmoid; a selection bias (DeepSeek-V3's
+``e_score_correction_bias``) moves only which experts are chosen, and
+the weights come from the unbiased scores. The Switch-style
+load-balance auxiliary loss is returned for routers without that bias
+(the bias is how the others balance).
 """
 
 from __future__ import annotations
@@ -42,6 +54,8 @@ from jax.sharding import PartitionSpec as P
 
 from .hints import current_rules
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def init_moe(cfg, key, dtype) -> Tuple[Dict, Dict]:
     m = cfg.moe
@@ -49,18 +63,22 @@ def init_moe(cfg, key, dtype) -> Tuple[Dict, Dict]:
     ks = jax.random.split(key, 5)
     sc_in, sc_out = float(1.0 / np.sqrt(d)), float(1.0 / np.sqrt(m.expert_d_ff))
     gated = cfg.act in ("swiglu", "geglu")
+    n = m.held_count()
     p = {
         "router": jax.random.normal(ks[0], (d, m.num_experts), jnp.float32) * sc_in,
-        "wi": jax.random.normal(ks[1], (m.num_experts, d, m.expert_d_ff), dtype) * sc_in,
-        "wo": jax.random.normal(ks[2], (m.num_experts, m.expert_d_ff, d), dtype) * sc_out,
+        "wi": jax.random.normal(ks[1], (n, d, m.expert_d_ff), dtype) * sc_in,
+        "wo": jax.random.normal(ks[2], (n, m.expert_d_ff, d), dtype) * sc_out,
     }
     s = {
         "router": (None, None),            # replicated: d·E is tiny
         "wi": ("expert", "embed", "mlp"),
         "wo": ("expert", "mlp", "embed"),
     }
+    if m.select_bias:
+        p["bias"] = jnp.zeros((m.num_experts,), jnp.float32)
+        s["bias"] = (None,)
     if gated:
-        p["wg"] = jax.random.normal(ks[3], (m.num_experts, d, m.expert_d_ff), dtype) * sc_in
+        p["wg"] = jax.random.normal(ks[3], (n, d, m.expert_d_ff), dtype) * sc_in
         s["wg"] = ("expert", "embed", "mlp")
     if m.num_shared_experts:
         ff_sh = m.num_shared_experts * m.shared_d_ff
@@ -87,19 +105,35 @@ def _act(h, g, act: str):
 # Shared core: local sorted-scatter dispatch + combine (shape-local)
 # ---------------------------------------------------------------------------
 
-def _route(router, cfg, xf):
-    """Returns (gate_vals [N,K], expert_ids [N,K], aux scalar)."""
+def _route(p, cfg, xf):
+    """Returns (gate_vals [N,K], expert_ids [N,K], load [E], aux scalar):
+    the weights and router-wide ids of each token's top-k experts, the
+    (token, slot) pairs choosing each expert, and the Switch loss (0 for
+    a router with a selection bias)."""
     m = cfg.moe
     N = xf.shape[0]
-    logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32), router)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, m.top_k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    counts = jnp.zeros((m.num_experts,), jnp.float32) \
-        .at[expert_ids.reshape(-1)].add(1.0)
-    frac = counts / (N * m.top_k)
-    aux = m.num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
-    return gate_vals, expert_ids, aux
+    logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32), p["router"],
+                        precision=HIGHEST if m.router_f32 else None)
+    if m.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if "bias" in p:
+        choice = scores + jax.lax.stop_gradient(p["bias"])
+    _, expert_ids = jax.lax.top_k(choice, m.top_k)
+    gate_vals = jnp.take_along_axis(scores, expert_ids, axis=-1)
+    if m.norm_topk:
+        gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                                 + 1e-20)
+    gate_vals = gate_vals * m.routed_scale
+    load = jnp.zeros((m.num_experts,), jnp.int32) \
+        .at[expert_ids.reshape(-1)].add(1)
+    if "bias" in p:
+        return gate_vals, expert_ids, load, jnp.zeros((), jnp.float32)
+    frac = load.astype(jnp.float32) / (N * m.top_k)
+    aux = m.num_experts * jnp.sum(frac * jnp.mean(scores, axis=0))
+    return gate_vals, expert_ids, load, aux
 
 
 def _dispatch_table(expert_ids, E: int, capacity: int):
@@ -160,7 +194,7 @@ def _apply_moe_global(p: Dict, cfg, x: jax.Array,
     b, s, d = x.shape
     N = b * s
     xf = x.reshape(N, d)
-    gate_vals, expert_ids, aux = _route(p["router"], cfg, xf)
+    gate_vals, expert_ids, _, aux = _route(p, cfg, xf)
     if capacity is None:
         capacity = max(1, int(math.ceil(N * m.top_k / m.num_experts
                                         * m.capacity_factor)))
@@ -195,6 +229,7 @@ def _apply_moe_local(p: Dict, cfg, x: jax.Array, ctx
                      ) -> Tuple[jax.Array, jax.Array]:
     mesh, rules = ctx
     m = cfg.moe
+    assert "bias" not in p, "the shard_map path has no selection bias"
     b, s, d = x.shape
     dp = rules["tokens"]
     dp = (dp,) if isinstance(dp, str) else tuple(dp)
@@ -242,7 +277,8 @@ def _apply_moe_local(p: Dict, cfg, x: jax.Array, ctx
             chunk = N_loc // G
             i = jax.lax.axis_index("model")
             xme = jax.lax.dynamic_slice_in_dim(xf, i * chunk, chunk, 0)
-            gate_vals, expert_ids, aux = _route(router, cfg, xme)
+            gate_vals, expert_ids, _, aux = _route({"router": router}, cfg,
+                                                   xme)
             cap = max(1, int(math.ceil(chunk * m.top_k / E
                                        * m.capacity_factor)))
             table, _ = _dispatch_table(expert_ids, E, cap)
@@ -259,7 +295,8 @@ def _apply_moe_local(p: Dict, cfg, x: jax.Array, ctx
         else:
             # TP experts: model-replicated dispatch, width-sharded FFN,
             # one token-space psum
-            gate_vals, expert_ids, aux = _route(router, cfg, xf)
+            gate_vals, expert_ids, _, aux = _route({"router": router}, cfg,
+                                                   xf)
             cap = max(1, int(math.ceil(N_loc * m.top_k / E
                                        * m.capacity_factor)))
             table, _ = _dispatch_table(expert_ids, E, cap)
@@ -284,11 +321,92 @@ def _apply_moe_local(p: Dict, cfg, x: jax.Array, ctx
     return y, aux
 
 
+# ---------------------------------------------------------------------------
+# Held path (dropless; one expert-parallel shard's experts)
+# ---------------------------------------------------------------------------
+
+def _dispatch(expert_ids, first: int, n: int):
+    """Pairs routed to held experts first, sorted by expert: ``(order
+    [M] of flat (token, slot) indices, held [M] whether each sorted pair
+    is held here, rows [n] pairs per held expert)``."""
+    local = expert_ids.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < n), local, n)
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+    return order, key[order] < n, rows
+
+
+def _use_gmm() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """(rows, contraction, output) tile: 128 rows, so a group wastes
+    under one tile, and the widths whole up to 1024 (halved until they
+    divide) so the grid stays short."""
+    def tile(x):
+        t = x
+        while t > 1024 and t % 2 == 0:
+            t //= 2
+        return t
+    return 128, tile(k), tile(n)
+
+
+def _grouped(lhs, rhs, rows):
+    """``lhs`` rows in groups of ``rows[g]`` times ``rhs[g]``; rows past
+    the groups come out zero."""
+    if not _use_gmm():
+        return jax.lax.ragged_dot(lhs, rhs, rows)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m = lhs.shape[0]
+    pad = -m % 128
+    if pad:
+        lhs = jnp.concatenate([lhs, jnp.zeros((pad,) + lhs.shape[1:],
+                                              lhs.dtype)])
+    # one more group that no expert here holds: gmm zeroes its rows
+    sizes = jnp.append(rows, m + pad - jnp.sum(rows)).astype(jnp.int32)
+    out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+              tiling=_gmm_tiling)
+    return out[:m]
+
+
+def _apply_moe_held(p: Dict, cfg, x: jax.Array):
+    m = cfg.moe
+    first, n = m.held
+    b, s, d = x.shape
+    N, K = b * s, m.top_k
+    xf = x.reshape(N, d)
+    with jax.named_scope("moe.route"):
+        gate_vals, expert_ids, load, aux = _route(p, cfg, xf)
+    with jax.named_scope("moe.dispatch"):
+        order, held, rows = _dispatch(expert_ids, first, n)
+        xs = xf[order // K]                            # [N·K, d]
+    with jax.named_scope("moe.experts"):
+        h = _grouped(xs, p["wi"], rows)
+        g = _grouped(xs, p["wg"], rows) if "wg" in p else None
+        ys = _grouped(_act(h, g, cfg.act), p["wo"], rows)
+    with jax.named_scope("moe.combine"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        w = jnp.where(held[inv], gate_vals.reshape(-1), 0.0)
+        y = jnp.einsum("nkd,nk->nd", ys[inv].reshape(N, K, d),
+                       w.reshape(N, K).astype(ys.dtype))
+    if m.num_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + _shared_experts(p, cfg, xf)
+    return y.reshape(b, s, d), aux, {"moe_load": load, "moe_rows": rows}
+
+
 def apply_moe(p: Dict, cfg, x: jax.Array,
               capacity: Optional[int] = None
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x [b, s, d] → (y [b, s, d], aux_loss scalar)."""
+              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """x [b, s, d] → (y [b, s, d], aux_loss scalar, counters): the held
+    path's counters are ``moe_load`` [E] (pairs choosing each expert) and
+    ``moe_rows`` [held] (rows each held expert computed); the other paths
+    return none."""
+    if cfg.moe.held is not None:
+        return _apply_moe_held(p, cfg, x)
     ctx = current_rules()
     if getattr(cfg, "moe_impl", "global") == "local" and ctx is not None:
-        return _apply_moe_local(p, cfg, x, ctx)
-    return _apply_moe_global(p, cfg, x, capacity)
+        return (*_apply_moe_local(p, cfg, x, ctx), {})
+    return (*_apply_moe_global(p, cfg, x, capacity), {})

@@ -116,9 +116,10 @@ def init_model(cfg: ModelConfig, key) -> Tuple[Dict, Dict]:
 def _apply_block(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
                  positions: jax.Array, mode: str,
                  cache: Optional[Dict], cache_capacity: Optional[int]
-                 ) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
-    """One decoder block. Returns (x, new_cache, aux_loss)."""
+                 ) -> Tuple[jax.Array, Optional[Dict], jax.Array, Dict]:
+    """One decoder block. Returns (x, new_cache, aux_loss, counters)."""
     aux = jnp.zeros((), jnp.float32)
+    stats: Dict[str, jax.Array] = {}
     x = hint(x, ("batch", None, None))
     h = apply_norm(p["norm1"], x, cfg.norm)
     new_cache = None
@@ -150,16 +151,16 @@ def _apply_block(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
     x = x + y
 
     if spec.mlp == "none":
-        return x, new_cache, aux
+        return x, new_cache, aux, stats
     h = apply_norm(p["norm2"], x, cfg.norm)
     with jax.named_scope("mlp" if spec.mlp == "dense" else "moe"):
         if spec.mlp == "dense":
             y = apply_mlp(p["mlp"], h, cfg.act)
         else:
-            y, aux = moe_mod.apply_moe(p["mlp"], cfg, h)
+            y, aux, stats = moe_mod.apply_moe(p["mlp"], cfg, h)
     if cfg.post_norms:
         y = apply_norm(p["post_mlp"], y, cfg.norm)
-    return x + y, new_cache, aux
+    return x + y, new_cache, aux, stats
 
 
 def _cache_capacity(cfg: ModelConfig, spec: LayerSpec, max_len: int) -> int:
@@ -178,10 +179,13 @@ def _run_stack(cfg: ModelConfig, params: Dict, x: jax.Array,
                positions: jax.Array, mode: str,
                caches: Optional[List] = None,
                max_len: Optional[int] = None, remat: bool = True
-               ) -> Tuple[jax.Array, Optional[List], jax.Array]:
+               ) -> Tuple[jax.Array, Optional[List], jax.Array, Dict]:
+    """Returns (x, caches, summed aux loss, counters); each counter is
+    stacked over the layers that report it, in layer order."""
     groups = layout_groups(cfg.default_layout())
     new_caches: List[Any] = []
     aux_total = jnp.zeros((), jnp.float32)
+    stats: Dict[str, List[jax.Array]] = {}
 
     for gi, (block, repeats) in enumerate(groups):
         stacked = params["groups"][gi]
@@ -189,26 +193,33 @@ def _run_stack(cfg: ModelConfig, params: Dict, x: jax.Array,
         def body(x, layer_inputs, block=block):
             layer_params, layer_cache = layer_inputs
             aux_l = jnp.zeros((), jnp.float32)
-            outs = []
+            outs, counted = [], {}
             for li, spec in enumerate(block):
                 c = layer_cache[li] if layer_cache is not None else None
                 cap = _cache_capacity(cfg, spec, max_len) if max_len else None
-                x, nc, aux = _apply_block(cfg, spec, layer_params[li], x,
-                                          positions, mode, c, cap)
+                x, nc, aux, st = _apply_block(cfg, spec, layer_params[li], x,
+                                              positions, mode, c, cap)
                 outs.append(nc)
                 aux_l = aux_l + aux
+                for k, v in st.items():
+                    counted.setdefault(k, []).append(v)
+            counted = {k: jnp.stack(v) for k, v in counted.items()}
             if any(o is not None for o in outs):
-                return x, (outs, aux_l)
-            return x, (None, aux_l)
+                return x, (outs, aux_l, counted)
+            return x, (None, aux_l, counted)
 
         body_fn = jax.checkpoint(body) if (remat and mode == "train") else body
         cache_in = caches[gi] if caches is not None else None
-        x, (cache_out, aux_stack) = jax.lax.scan(
+        x, (cache_out, aux_stack, counted) = jax.lax.scan(
             body_fn, x, (stacked, cache_in))
         aux_total = aux_total + jnp.sum(aux_stack)
+        for k, v in counted.items():       # [repeats, per block, ...]
+            stats.setdefault(k, []).append(v.reshape((-1,) + v.shape[2:]))
         new_caches.append(cache_out)
 
-    return x, (new_caches if mode in ("prefill", "decode") else None), aux_total
+    stats = {k: jnp.concatenate(v) for k, v in stats.items()}
+    return (x, (new_caches if mode in ("prefill", "decode") else None),
+            aux_total, stats)
 
 
 # ---------------------------------------------------------------------------
@@ -244,36 +255,49 @@ def _inputs_to_hidden(cfg: ModelConfig, params: Dict, batch: Dict
 # Public entry points
 # ---------------------------------------------------------------------------
 
-def forward(cfg: ModelConfig, params: Dict, batch: Dict,
-            remat: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence logits (training). Returns (logits, aux_loss)."""
+def _forward(cfg: ModelConfig, params: Dict, batch: Dict, remat: bool
+             ) -> Tuple[jax.Array, jax.Array, Dict]:
     with jax.named_scope("embed"):
         x, positions = _inputs_to_hidden(cfg, params, batch)
     with jax.named_scope("layers"):
-        x, _, aux = _run_stack(cfg, params, x, positions, "train",
-                               remat=remat)
+        x, _, aux, stats = _run_stack(cfg, params, x, positions, "train",
+                                      remat=remat)
     with jax.named_scope("head"):
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        return lm_logits(params["embed"], cfg, x), aux
+        return lm_logits(params["embed"], cfg, x), aux, stats
 
 
-def train_loss(cfg: ModelConfig, params: Dict, batch: Dict,
-               remat: bool = True) -> jax.Array:
-    logits, aux = forward(cfg, params, batch, remat=remat)
+def forward(cfg: ModelConfig, params: Dict, batch: Dict,
+            remat: bool = True) -> Tuple[jax.Array, jax.Array]:
+    """Full-sequence logits (training). Returns (logits, aux_loss)."""
+    return _forward(cfg, params, batch, remat)[:2]
+
+
+def train_loss_and_stats(cfg: ModelConfig, params: Dict, batch: Dict,
+                         remat: bool = True) -> Tuple[jax.Array, Dict]:
+    """(loss, counters): the layers' counters (``moe_load``, ``moe_rows``
+    of held-expert layers, stacked over those layers); none for a model
+    without them."""
+    logits, aux, stats = _forward(cfg, params, batch, remat)
     labels = batch["labels"]
     if cfg.input_mode == "tokens+prefix":
         logits = logits[:, cfg.prefix_len:, :]  # loss on text positions only
     with jax.named_scope("loss"):
         loss = cross_entropy(logits, labels, batch.get("loss_mask"))
-    return loss + AUX_LOSS_WEIGHT * aux
+    return loss + AUX_LOSS_WEIGHT * aux, stats
+
+
+def train_loss(cfg: ModelConfig, params: Dict, batch: Dict,
+               remat: bool = True) -> jax.Array:
+    return train_loss_and_stats(cfg, params, batch, remat)[0]
 
 
 def prefill(cfg: ModelConfig, params: Dict, batch: Dict, max_len: int
             ) -> Tuple[jax.Array, List]:
     """Run the prompt; returns (last-position logits, caches)."""
     x, positions = _inputs_to_hidden(cfg, params, batch)
-    x, caches, _ = _run_stack(cfg, params, x, positions, "prefill",
-                              max_len=max_len, remat=False)
+    x, caches, _, _ = _run_stack(cfg, params, x, positions, "prefill",
+                                 max_len=max_len, remat=False)
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = lm_logits(params["embed"], cfg, x[:, -1:, :])
     return logits, caches
@@ -288,9 +312,9 @@ def decode_step(cfg: ModelConfig, params: Dict, tokens: jax.Array,
     else:
         batch = {"tokens": tokens, "positions": pos}
     x, positions = _inputs_to_hidden(cfg, params, batch)
-    x, caches, _ = _run_stack(cfg, params, x, positions, "decode",
-                              caches=caches,
-                              max_len=int(caches_max_len(caches)))
+    x, caches, _, _ = _run_stack(cfg, params, x, positions, "decode",
+                                 caches=caches,
+                                 max_len=int(caches_max_len(caches)))
     x = apply_norm(params["final_norm"], x, cfg.norm)
     return lm_logits(params["embed"], cfg, x), caches
 

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from ..models import decode_step as model_decode
 from ..models import prefill as model_prefill
-from ..models import train_loss
+from ..models import train_loss_and_stats
 from ..optim import AdamWConfig, adamw_update
 
 
@@ -34,14 +34,18 @@ class TrainConfig:
 
 def make_train_step(cfg, tcfg: TrainConfig = TrainConfig()
                     ) -> Callable:
-    """(params, opt_state, batch) → (params, opt_state, metrics)."""
+    """(params, opt_state, batch) → (params, opt_state, metrics). The
+    metrics hold the model's counters (``train_loss_and_stats``) summed
+    over the step's rows, on the device."""
 
     def loss_fn(params, batch):
-        return train_loss(cfg, params, batch, remat=tcfg.remat)
+        return train_loss_and_stats(cfg, params, batch, remat=tcfg.remat)
+
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def train_step(params, opt_state, batch):
         if tcfg.microbatches == 1:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss, stats), grads = grad_fn(params, batch)
         else:
             n = tcfg.microbatches
 
@@ -56,15 +60,16 @@ def make_train_step(cfg, tcfg: TrainConfig = TrainConfig()
 
             def acc_fn(carry, mb):
                 loss_acc, g_acc = carry
-                loss, g = jax.value_and_grad(loss_fn)(params, mb)
+                (loss, st), g = grad_fn(params, mb)
                 g_acc = jax.tree_util.tree_map(
                     lambda a, x: a + x.astype(jnp.float32) / n, g_acc, g)
-                return (loss_acc + loss / n, g_acc), None
+                return (loss_acc + loss / n, g_acc), st
 
-            (loss, grads), _ = jax.lax.scan(acc_fn, (0.0, zero), micro)
+            (loss, grads), st = jax.lax.scan(acc_fn, (0.0, zero), micro)
+            stats = jax.tree_util.tree_map(lambda x: jnp.sum(x, 0), st)
         params, opt_state, om = adamw_update(params, grads, opt_state,
                                              tcfg.optimizer)
-        metrics = {"loss": loss, **om}
+        metrics = {"loss": loss, **om, **stats}
         return params, opt_state, metrics
 
     return train_step

@@ -109,6 +109,7 @@ def test_param_counts_sane():
         "stablelm-1.6b": (1.6e9, 0.45),
         "phi-3-vision-4.2b": (4.2e9, 0.45),
         "musicgen-large": (3.3e9, 0.75),
+        "kanana-2-30b-a3b": (30e9, 0.10),
     }
     for arch, (want, tol) in expect.items():
         cfg = get_config(arch)

@@ -36,13 +36,13 @@ SCRIPT = textwrap.dedent("""
     p, _ = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32), jnp.float32)
 
-    y_ref, aux_ref = apply_moe(p, dataclasses.replace(cfg,
-                                                      moe_impl="global"),
-                               x)
+    y_ref, aux_ref, _ = apply_moe(p, dataclasses.replace(cfg,
+                                                         moe_impl="global"),
+                                  x)
 
     with mesh, activation_rules(mesh, rules):
         xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
-        y, aux = jax.jit(lambda p, x: apply_moe(p, cfg, x))(p, xs)
+        y, aux, _ = jax.jit(lambda p, x: apply_moe(p, cfg, x))(p, xs)
 
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=2e-4, atol=2e-4)

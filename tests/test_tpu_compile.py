@@ -80,3 +80,20 @@ def test_missing_mask_x64_kernel_compiles_for_v5e(one_chip):
                             [((4,), jnp.int64), ((DOTS // 64,), jnp.int64),
                              ((DOTS,), jnp.int64)], one_chip)
     assert compiled.memory_analysis().argument_size_in_bytes > 8 * DOTS
+
+
+def test_held_experts_grouped_matmul_compiles_for_v5e(one_chip, monkeypatch):
+    """The held experts' SwiGLU matmuls through megablox ``gmm``, forward
+    and gradient, at the MoE training cell's widths: 8,192 tokens x top-6
+    sorted rows of 2,048, 8 held experts of 768."""
+    from repro.models import moe
+    monkeypatch.setattr(moe, "_use_gmm", lambda: True)
+
+    def loss(x, wi, wo, rows):
+        return jnp.sum(moe._grouped(moe._grouped(x, wi, rows), wo, rows)
+                       .astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), [
+        ((8192 * 6, 2048), jnp.bfloat16), ((8, 2048, 768), jnp.bfloat16),
+        ((8, 768, 2048), jnp.bfloat16), ((8,), jnp.int32)], one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
